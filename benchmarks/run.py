@@ -316,6 +316,9 @@ def bench_roofline_summary():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
     bench_kernels()

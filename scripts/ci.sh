@@ -59,7 +59,7 @@ fi
 # rename/deselection that silently drops one is a coverage regression,
 # not a green build.
 REQUIRED_SUITES=(api properties kernels quantized graph serve sharded
-                 mutation autotune)
+                 mutation autotune tpu_compile)
 for suite in "${REQUIRED_SUITES[@]}"; do
     if ! grep -q "test_${suite}" <<<"$collect_out"; then
         echo "FATAL: tests/test_${suite}.py not collected" >&2

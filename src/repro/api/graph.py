@@ -258,9 +258,10 @@ class HNSWIndex(VectorIndex):
         ef = max(ef_base, k_req)
         t0 = time.perf_counter()
         if self._use_batched(q.shape[0]):
+            driver = hnsw_lib.batched_driver()
             scores, idx, evals, hops = hnsw_lib.search_batched(
-                self._g, q, k_req, ef_search=ef, frontier=self.frontier,
-                alive=alive)
+                self._g, q, k_req, ef_search=ef, impl=driver,
+                frontier=self.frontier, alive=alive)
             g = self._g
             row_bytes = (g.codec.gather_bytes if g.codec is not None
                          else 4 * g.vecs.shape[1] + 4)
@@ -271,7 +272,9 @@ class HNSWIndex(VectorIndex):
                      # quantized, f32 row+norm otherwise) — the bandwidth
                      # axis the graph bench gates
                      "gather_bytes_per_hop":
-                         float(evals.sum() * row_bytes) / max(hops, 1)}
+                         float(evals.sum() * row_bytes) / max(hops, 1),
+                     # 1.0 when the layer-0 hops ran the Pallas kernel
+                     "pallas_hop": float(driver == "fused")}
         else:
             scores, idx, evals = hnsw_lib.search(self._g, q, k_req,
                                                  ef_search=ef, alive=alive)

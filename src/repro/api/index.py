@@ -350,10 +350,11 @@ class FlatIndex(VectorIndex):
         self.metric = metric
         self.ctx = ctx
         self._db: Optional[jax.Array] = None
+        self._n = 0  # real rows; a mesh-sharded _db carries pad rows after
 
     @property
     def ntotal(self) -> int:
-        return 0 if self._db is None else int(self._db.shape[0])
+        return self._n
 
     @property
     def built(self) -> bool:
@@ -369,27 +370,37 @@ class FlatIndex(VectorIndex):
         self._require_built()
         return int(self._db.shape[1])
 
+    def _rows(self) -> np.ndarray:
+        return np.asarray(self._db)[:self._n]
+
     def _fingerprint_state(self) -> list:
-        return [self.metric, self._db]
+        return [self.metric, self._rows()]
 
     def build(self, corpus: np.ndarray) -> "FlatIndex":
-        self._db = jnp.asarray(corpus, jnp.float32)
+        corpus = np.asarray(corpus, np.float32)
+        self._db = ds.place_rows(corpus, self.ctx)
+        self._n = int(corpus.shape[0])
         return self
 
     @functools.cached_property
     def _scan(self):
         return jax.jit(
-            lambda q, db, alive, k: ds.search(q, db, k, self.ctx,
-                                              metric=self.metric,
-                                              alive=alive),
-            static_argnames=("k",))
+            lambda q, db, alive, k, n: ds.search(q, db, k, self.ctx,
+                                                 metric=self.metric,
+                                                 alive=alive, n=n),
+            static_argnames=("k", "n"))
 
     def add(self, vecs: np.ndarray) -> None:
         """Streaming insert: append rows to the scanned corpus. New rows
         are searchable immediately; existing rows keep their ids."""
         self._require_built()
-        self._db = jnp.concatenate(
-            [self._db, jnp.asarray(vecs, jnp.float32)], axis=0)
+        nv = np.asarray(vecs, np.float32)
+        if self.ctx.mesh is None:
+            self._db = jnp.concatenate([self._db, jnp.asarray(nv)], axis=0)
+        else:  # re-place: the sharded slabs are padded to equal size
+            self._db = ds.place_rows(np.concatenate([self._rows(), nv]),
+                                     self.ctx)
+        self._n += int(nv.shape[0])
 
     def search(self, queries: np.ndarray, k: int,
                alive: Optional[np.ndarray] = None,
@@ -399,19 +410,17 @@ class FlatIndex(VectorIndex):
         q = jnp.asarray(queries, jnp.float32)
         al = None if alive is None else jnp.asarray(np.asarray(alive, bool))
         return _timed(lambda: self._scan(q, self._db, al,
-                                         k=min(k, self.ntotal)),
+                                         k=min(k, self.ntotal), n=self._n),
                       stats={"distance_evals": float(self.ntotal)})
 
     def save(self, directory: str) -> None:
         self._require_built()
         _save_dir(directory, {"kind": self.kind, "metric": self.metric},
-                  {"db": np.asarray(self._db)})
+                  {"db": self._rows()})
 
     @classmethod
     def _load(cls, directory: str, meta: dict[str, Any]) -> "FlatIndex":
-        self = cls(metric=meta["metric"])
-        self._db = jnp.asarray(_load_arrays(directory)["db"])
-        return self
+        return cls(metric=meta["metric"]).build(_load_arrays(directory)["db"])
 
 
 # ---------------------------------------------------------------------------

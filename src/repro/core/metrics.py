@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.common import EXACT
+
 
 def pairwise_distances(q: jax.Array, db: jax.Array, metric: str = "euclidean",
                        chunk: int = 1024) -> jax.Array:
@@ -21,11 +23,12 @@ def pairwise_distances(q: jax.Array, db: jax.Array, metric: str = "euclidean",
     if metric == "cosine":
         qn = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         dn = db / jnp.maximum(jnp.linalg.norm(db, axis=-1, keepdims=True), 1e-12)
-        return 1.0 - qn @ dn.T
+        return 1.0 - jnp.matmul(qn, dn.T, precision=EXACT)
     if metric == "euclidean":
         q2 = jnp.sum(q * q, -1)[:, None]
         d2 = jnp.sum(db * db, -1)[None, :]
-        sq = jnp.maximum(q2 - 2.0 * q @ db.T + d2, 0.0)
+        sq = jnp.maximum(
+            q2 - 2.0 * jnp.matmul(q, db.T, precision=EXACT) + d2, 0.0)
         return jnp.sqrt(sq)
     raise ValueError(f"unknown metric {metric!r}")
 

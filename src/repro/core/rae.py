@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..configs.base import RAEConfig
 from ..distributed.partitioning import ParamDef
+from ..kernels.common import EXACT
 
 Params = dict[str, jax.Array]
 
@@ -49,14 +50,14 @@ def init(cfg: RAEConfig, key: jax.Array) -> Params:
 
 def encode(params: Params, x: jax.Array) -> jax.Array:
     """f(x) = x @ W_e (+ b_e). x: [..., n] -> [..., m]."""
-    y = x @ params["w_e"]
+    y = jnp.matmul(x, params["w_e"], precision=EXACT)
     if "b_e" in params:
         y = y + params["b_e"]
     return y
 
 
 def decode(params: Params, z: jax.Array) -> jax.Array:
-    y = z @ params["w_d"]
+    y = jnp.matmul(z, params["w_d"], precision=EXACT)
     if "b_d" in params:
         y = y + params["b_d"]
     return y

@@ -1,4 +1,7 @@
-"""Pallas TPU kernels (validated via interpret=True on CPU; ref.py oracles).
+"""Pallas TPU kernels. On a TPU the ops dispatch to the compiled kernel;
+on the CPU the tests run each kernel with interpret=True against its
+ref.py oracle, and tests/test_tpu_compile.py compiles them for a
+described v5e chip.
 
 l2_topk       — fused distance + online top-k scan (the retrieval hot path)
 rae_encode    — RAE encoder GEMM + fused L2-normalize epilogue

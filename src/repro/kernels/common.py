@@ -33,6 +33,12 @@ PAD_ID = -1
 #: (ops-layer row padding: a padded db/code row must never win the scan).
 PAD_PENALTY = 1e30
 
+#: Matmul precision of every distance and encode contraction. A TPU f32
+#: dot at default precision rounds its operands to bf16 (one MXU pass),
+#: and exact search must agree with an f32 brute force; the CPU computes
+#: f32 either way.
+EXACT = "highest"
+
 
 def canonicalize_pads(vals, ids):
     """Pin every pad slot of a merged (vals, ids) pair to the canonical

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import NEG_INF, graph_beam_pallas
+from .kernel import NEG_INF, gather_layout, graph_beam_pallas
 from .ref import graph_beam_ref
 
 
@@ -30,6 +30,7 @@ def _next_pow2(n: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_padded(queries, db, db_sq, nbr_ids, beam_v, beam_i, interpret):
+    db, db_sq = gather_layout(db, db_sq)
     return graph_beam_pallas(queries, db, db_sq, nbr_ids, beam_v, beam_i,
                              interpret=interpret)
 

@@ -9,8 +9,9 @@ at million-vector scale the hop is bandwidth-bound on exactly this DMA.
 Same house idioms as the f32 hop, plus the codec algebra:
 
 * *scalar-prefetch gather*: neighbor ids prefetched into SMEM drive the
-  code-row BlockSpec index map, so each grid step DMAs exactly one code
-  row HBM->VMEM — the [Q, W, C] gather never exists;
+  code-row BlockSpec index map, so each grid step DMAs the one sublane
+  tile holding the wanted code row HBM->VMEM — the [Q, W, C] gather never
+  exists (same tile layout as the f32 hop, ``gather_layout``);
 * scoring is the unified affine form ``contract(q_op, code_row) +
   q_bias - node_bias`` (see ``ref.py``): SQ8 contracts the pre-scaled
   query against the raw codes (dequant-free asymmetric L2, the
@@ -38,41 +39,45 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import NEG_INF
-from ..l2_topk.kernel import _set_col, _topk_update
+from ..graph_beam.kernel import (_LANES, _SUBLANES, merge_into_row,
+                                 pick_lane, pick_row, tile_rows)
 
 
-def _kernel(safe_ref, raw_ref, qop_ref, qb_ref, code_ref, nb_ref, bv_ref,
-            bi_ref, vout_ref, iout_ref, cv_ref, ci_ref, *, w_slots: int,
-            ef: int, mode: str, ksub: int):
+def _kernel(ids_ref, qop_ref, qb_ref, code_ref, nb_ref, bv_ref, bi_ref,
+            vout_ref, iout_ref, cv_ref, ci_ref, *, w_slots: int, ef: int,
+            mode: str, ksub: int, rows: int):
     i = pl.program_id(0)
     w = pl.program_id(1)
-    raw = raw_ref[i * w_slots + w]
-    qop = qop_ref[...].astype(jnp.float32)               # [1, Dop]
-    c = code_ref[...]                                    # [1, C] int32
+    raw = ids_ref[i * w_slots + w]
+    safe = jnp.maximum(raw, 0)
+    qop = pick_row(qop_ref[...], i).astype(jnp.float32)           # [1, Dop]
+    c = pick_row(code_ref[...], safe % rows)                       # [1, C]
     if mode == "sq8":
-        contrib = jnp.sum(qop * c.astype(jnp.float32))
+        contrib = jnp.sum(qop * c.astype(jnp.float32), axis=1,
+                          keepdims=True)
     else:
-        # one-hot row [m, ksub]: oh[mm, j] = (codes[mm] == j); contracting
-        # it against the flat LUT operand IS the per-subspace LUT gather
+        # one-hot row [1, m*ksub]: oh[0, mm*ksub + j] = (codes[mm] == j);
+        # contracting it against the flat LUT operand IS the per-subspace
+        # LUT gather
         m = c.shape[1]
-        oh = (c.reshape(m, 1)
-              == jax.lax.broadcasted_iota(jnp.int32, (m, ksub), 1))
-        contrib = jnp.sum(qop * oh.astype(jnp.float32).reshape(1, m * ksub))
-    s = contrib + qb_ref[0] - nb_ref[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (m, m * ksub), 1)
+        sub = jax.lax.broadcasted_iota(jnp.int32, (m, m * ksub), 0)
+        want = jnp.transpose(c) + sub * ksub                       # [m, 1]
+        oh = jnp.sum(jnp.where(lane == want, 1.0, 0.0), axis=0,
+                     keepdims=True)                                # [1, m*ksub]
+        contrib = jnp.sum(qop * oh, axis=1, keepdims=True)
+    qb = pick_row(qb_ref[...], i)                                  # [1, 1]
+    nb = pick_lane(nb_ref[...], safe % (_SUBLANES * _LANES))       # [1, 1]
+    s = contrib + qb - nb
     s = jnp.where(raw < 0, NEG_INF, s)
-    cv_ref[...] = _set_col(cv_ref[...], w, s.reshape(1))
-    ci_ref[...] = _set_col(ci_ref[...], w, raw.reshape(1))
+    col = jax.lax.broadcasted_iota(jnp.int32, cv_ref.shape, 1)
+    cv_ref[...] = jnp.where(col == w, s, cv_ref[...])
+    ci_ref[...] = jnp.where(col == w, raw, ci_ref[...])
 
     @pl.when(w == w_slots - 1)
     def _():
-        nv, ni = _topk_update(bv_ref[...].astype(jnp.float32), bi_ref[...],
-                              cv_ref[...], ci_ref[...], ef)
-        # exhausted slots re-pick the first NEG_INF tie; canonicalize them
-        # to (NEG_INF, -1) exactly like the ref
-        ni = jnp.where(nv <= NEG_INF, -1, ni)
-        nv = jnp.where(ni >= 0, nv, NEG_INF)
-        vout_ref[...] = nv
-        iout_ref[...] = ni
+        merge_into_row(i, bv_ref, bi_ref, cv_ref[...], ci_ref[...],
+                       vout_ref, iout_ref, ef)
 
 
 def graph_beam_q_pallas(q_op: jax.Array, q_bias: jax.Array, codes: jax.Array,
@@ -80,36 +85,46 @@ def graph_beam_q_pallas(q_op: jax.Array, q_bias: jax.Array, codes: jax.Array,
                         beam_v: jax.Array, beam_i: jax.Array, *, mode: str,
                         ksub: int = 0, interpret: bool = False
                         ) -> tuple[jax.Array, jax.Array]:
-    """q_op [Q, Dop] f32, q_bias [Q] f32, codes [N, C] int32 (ops.py
-    widens the stored uint8 — TPU tiling), node_bias [N] f32, nbr_ids
-    [Q, W] int32 (-1 = masked), beam_v/beam_i [Q, ef]. Returns the merged
-    beam, sorted descending. ``ops.py`` pads Q; W and ef ride as-is
-    (sub-tile blocks, same as the f32 hop)."""
+    """q_op [Q, Dop] f32, q_bias [Q] f32, codes [N', C] int32 and
+    node_bias [R, 128] f32 as laid out by
+    :func:`repro.kernels.graph_beam.kernel.gather_layout` from the int32
+    codes (``ops.py`` widens the stored uint8 — TPU tiling) and the [N]
+    per-node bias, nbr_ids [Q, W] int32 (-1 = masked), beam_v/beam_i
+    [Q, ef]. Returns the merged beam, sorted descending."""
     qn, dop = q_op.shape
     cw = codes.shape[1]
     w_slots = nbr_ids.shape[1]
     ef = beam_v.shape[1]
+    rows = tile_rows(codes.dtype)
     ids = nbr_ids.reshape(-1)
-    safe = jnp.clip(ids, 0, codes.shape[0] - 1)
     kernel = functools.partial(_kernel, w_slots=w_slots, ef=ef, mode=mode,
-                               ksub=ksub)
+                               ksub=ksub, rows=rows)
+
+    def node(i, w, ids):
+        return jnp.maximum(ids[i * w_slots + w], 0)
+
+    def whole(i, w, ids):
+        return 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # clamped ids (drive the DMA) + raw ids
+        num_scalar_prefetch=1,  # neighbor ids: drive the code-row DMAs
         grid=(qn, w_slots),
         in_specs=[
-            pl.BlockSpec((1, dop), lambda i, w, safe, raw: (i, 0)),
-            pl.BlockSpec((1,), lambda i, w, safe, raw: (i,)),
-            # one code row + its bias per grid step, id-selected
-            pl.BlockSpec((1, cw),
-                         lambda i, w, safe, raw: (safe[i * w_slots + w], 0)),
-            pl.BlockSpec((1,),
-                         lambda i, w, safe, raw: (safe[i * w_slots + w],)),
-            pl.BlockSpec((1, ef), lambda i, w, safe, raw: (i, 0)),
-            pl.BlockSpec((1, ef), lambda i, w, safe, raw: (i, 0)),
+            pl.BlockSpec((qn, dop), whole),
+            pl.BlockSpec((qn, 1), whole),
+            # the sublane tile holding the neighbor's code row, and the
+            # (8, 128) tile holding its bias, id-selected
+            pl.BlockSpec((rows, cw), lambda i, w, ids: (node(i, w, ids)
+                                                        // rows, 0)),
+            pl.BlockSpec((_SUBLANES, _LANES),
+                         lambda i, w, ids: (node(i, w, ids)
+                                            // (_SUBLANES * _LANES), 0)),
+            pl.BlockSpec((qn, ef), whole),
+            pl.BlockSpec((qn, ef), whole),
         ],
         out_specs=[
-            pl.BlockSpec((1, ef), lambda i, w, safe, raw: (i, 0)),
-            pl.BlockSpec((1, ef), lambda i, w, safe, raw: (i, 0)),
+            pl.BlockSpec((qn, ef), whole),
+            pl.BlockSpec((qn, ef), whole),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, w_slots), jnp.float32),
@@ -123,5 +138,5 @@ def graph_beam_q_pallas(q_op: jax.Array, q_bias: jax.Array, codes: jax.Array,
             jax.ShapeDtypeStruct((qn, ef), jnp.int32),
         ],
         interpret=interpret,
-    )(safe, ids, q_op, q_bias, codes, node_bias, beam_v, beam_i)
+    )(ids, q_op, q_bias.reshape(qn, 1), codes, node_bias, beam_v, beam_i)
     return vals, idx

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..graph_beam.kernel import gather_layout
 from .kernel import NEG_INF, graph_beam_q_pallas
 from .ref import graph_beam_q_ref
 
@@ -35,8 +36,9 @@ def _next_pow2(n: int) -> int:
 @functools.partial(jax.jit, static_argnames=("mode", "ksub", "interpret"))
 def _pallas_padded(q_op, q_bias, codes, node_bias, nbr_ids, beam_v, beam_i,
                    mode, ksub, interpret):
-    return graph_beam_q_pallas(q_op, q_bias, codes.astype(jnp.int32),
-                               node_bias, nbr_ids, beam_v, beam_i,
+    codes, node_bias = gather_layout(codes.astype(jnp.int32), node_bias)
+    return graph_beam_q_pallas(q_op, q_bias, codes, node_bias, nbr_ids,
+                               beam_v, beam_i,
                                mode=mode, ksub=ksub, interpret=interpret)
 
 

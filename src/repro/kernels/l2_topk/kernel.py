@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import NEG_INF
+from ..common import EXACT, NEG_INF
 
 
 def _topk_update(run_v, run_i, cand_v, cand_i, k: int):
@@ -34,13 +34,16 @@ def _topk_update(run_v, run_i, cand_v, cand_i, k: int):
 
     def body(j, carry):
         allv, outv, outi = carry
-        m = jnp.max(allv, axis=1)                      # [bq]
-        am = jnp.argmax(allv, axis=1)                  # [bq]
-        outv = outv.at[:, j].set(m) if False else _set_col(outv, j, m)
-        gi = jnp.take_along_axis(alli, am[:, None], axis=1)[:, 0]
+        m = jnp.max(allv, axis=1, keepdims=True)       # [bq, 1]
+        # first column holding the max (argmax's tie rule); the id is then
+        # read with a masked sum — Mosaic has no in-kernel gather
+        am = jnp.min(jnp.where(allv == m, col, width), axis=1,
+                     keepdims=True)
+        hit = col == am
+        gi = jnp.sum(jnp.where(hit, alli, 0), axis=1)
+        outv = _set_col(outv, j, m[:, 0])
         outi = _set_col(outi, j, gi)
-        # mask the selected entry
-        allv = jnp.where(col == am[:, None], NEG_INF, allv)
+        allv = jnp.where(hit, NEG_INF, allv)           # mask the selected
         return allv, outv, outi
 
     allv, outv, outi = jax.lax.fori_loop(0, k, body, (allv, outv, outi))
@@ -65,8 +68,8 @@ def _kernel(q_ref, db_ref, d2_ref, vals_ref, idx_ref, acc_v, acc_i, *,
     q = q_ref[...]
     d = db_ref[...]
     # similarity = 2 q·d - ||d||^2 (the -||q||^2 constant is added by ops.py)
-    s = 2.0 * jnp.dot(q, d.T, preferred_element_type=jnp.float32) \
-        - d2_ref[...][None, :]
+    s = 2.0 * jnp.dot(q, d.T, precision=EXACT,
+                      preferred_element_type=jnp.float32) - d2_ref[...]
     base = j * bn
     cand_i = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     nv, ni = _topk_update(acc_v[...], acc_i[...], s, cand_i, k)
@@ -94,7 +97,7 @@ def l2_topk_pallas(queries: jax.Array, db: jax.Array, db_sq: jax.Array,
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
@@ -109,5 +112,5 @@ def l2_topk_pallas(queries: jax.Array, db: jax.Array, db_sq: jax.Array,
             pltpu.VMEM((bq, k), jnp.int32),
         ],
         interpret=interpret,
-    )(queries, db, db_sq)
+    )(queries, db, db_sq.reshape(1, n))
     return vals, idx

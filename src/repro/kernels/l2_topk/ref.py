@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..common import NEG_INF, PAD_ID, canonicalize_pads
+from ..common import EXACT, NEG_INF, PAD_ID, canonicalize_pads
 
 
 def l2_topk_ref(queries: jax.Array, db: jax.Array, k: int,
@@ -18,10 +18,11 @@ def l2_topk_ref(queries: jax.Array, db: jax.Array, k: int,
     q = queries.astype(jnp.float32)
     d = db.astype(jnp.float32)
     if metric == "euclidean":
-        s = 2.0 * q @ d.T - jnp.sum(d * d, -1)[None, :] \
+        s = 2.0 * jnp.matmul(q, d.T, precision=EXACT) \
+            - jnp.sum(d * d, -1)[None, :] \
             - jnp.sum(q * q, -1)[:, None]
     elif metric == "cosine":
-        s = q @ d.T
+        s = jnp.matmul(q, d.T, precision=EXACT)
     else:
         raise ValueError(metric)
     if db_mask is None:

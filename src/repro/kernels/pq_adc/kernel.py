@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import NEG_INF
+from ..common import EXACT, NEG_INF
 from ..l2_topk.kernel import _topk_update
 
 
@@ -44,7 +44,7 @@ def _kernel(q_ref, cb_ref, codes_ref, pen_ref, vals_ref, idx_ref,
             qs = q[:, mm * dsub:(mm + 1) * dsub]        # [bq, dsub]
             cbm = cb_ref[mm * ksub:(mm + 1) * ksub, :]  # [ksub, dsub]
             lut_m = (jnp.sum(qs * qs, 1)[:, None]
-                     - 2.0 * jnp.dot(qs, cbm.T,
+                     - 2.0 * jnp.dot(qs, cbm.T, precision=EXACT,
                                      preferred_element_type=jnp.float32)
                      + jnp.sum(cbm * cbm, 1)[None, :])
             lut_ref[:, mm * ksub:(mm + 1) * ksub] = lut_m
@@ -54,9 +54,9 @@ def _kernel(q_ref, cb_ref, codes_ref, pen_ref, vals_ref, idx_ref,
     oh = (codes[:, :, None]
           == jax.lax.broadcasted_iota(jnp.int32, (bn, m, ksub), 2))
     oh = oh.astype(jnp.float32).reshape(bn, m * ksub)
-    dist = jnp.dot(lut_ref[...], oh.T,
+    dist = jnp.dot(lut_ref[...], oh.T, precision=EXACT,
                    preferred_element_type=jnp.float32)  # [bq, bn]
-    s = -dist - pen_ref[...][None, :]
+    s = -dist - pen_ref[...]
     cand_i = j * bn + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     nv, ni = _topk_update(acc_v[...], acc_i[...], s, cand_i, k)
     acc_v[...] = nv
@@ -86,7 +86,7 @@ def pq_adc_pallas(queries: jax.Array, codebooks_flat: jax.Array,
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((m * ksub, dsub), lambda i, j: (0, 0)),
             pl.BlockSpec((bn, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
@@ -102,5 +102,5 @@ def pq_adc_pallas(queries: jax.Array, codebooks_flat: jax.Array,
             pltpu.VMEM((bq, k), jnp.int32),
         ],
         interpret=interpret,
-    )(queries, codebooks_flat, codes, penalty)
+    )(queries, codebooks_flat, codes, penalty.reshape(1, n))
     return vals, idx

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..common import EXACT
+
 
 def pq_adc_ref(queries: jax.Array, codebooks: jax.Array, codes: jax.Array,
                k: int) -> tuple[jax.Array, jax.Array]:
@@ -19,7 +21,7 @@ def pq_adc_ref(queries: jax.Array, codebooks: jax.Array, codes: jax.Array,
     n = codes.shape[0]
     qs = q.reshape(qn, m, dsub)
     lut = (jnp.sum(qs * qs, -1)[:, :, None]
-           - 2 * jnp.einsum("qms,mjs->qmj", qs, cb)
+           - 2 * jnp.einsum("qms,mjs->qmj", qs, cb, precision=EXACT)
            + jnp.sum(cb * cb, -1)[None, :, :])          # [Q, m, ksub]
     lut_flat = lut.reshape(qn, m * ksub)
     offs = (codes.astype(jnp.int32)

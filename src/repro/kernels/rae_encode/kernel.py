@@ -18,13 +18,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import EXACT
+
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, normalize: bool):
     @pl.when(pl.program_id(1) == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...], precision=EXACT,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)

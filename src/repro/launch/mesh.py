@@ -1,39 +1,14 @@
 """Production mesh builders.
 
 Functions, not module-level constants, so importing this module never
-touches jax device state (the dry-run must set XLA_FLAGS first).
-
-``AxisType`` compatibility: ``jax.sharding.AxisType`` (and the matching
-``axis_types=`` kwarg of ``jax.make_mesh``) only exist in newer jax. On
-older installs we substitute an enum-shaped stand-in and drop the kwarg —
-every mesh here is Auto-typed anyway, which is the old default. Import
-``AxisType`` / ``make_mesh`` from THIS module, not from ``jax.sharding``.
+touches jax device state (the dry-run must set XLA_FLAGS first). Every
+mesh here is Auto-typed: shardings are propagated by the compiler, and
+``jax.make_mesh`` would otherwise default to Explicit axes.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:
-    from jax.sharding import AxisType  # jax >= 0.5
-except ImportError:
-    class AxisType:
-        """Stand-in for jax.sharding.AxisType on older jax."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh(shape, axes, axis_types=None):
-    """``jax.make_mesh`` that tolerates jax without ``axis_types``."""
-    if axis_types is not None and _MAKE_MESH_TAKES_AXIS_TYPES:
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,11 +18,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1D (data,) mesh — used by tests
     and the CPU-scale examples."""
     n = len(jax.devices())
-    return make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

@@ -45,6 +45,7 @@ import numpy as np
 from .. import api
 from ..data import synthetic
 from ..serve import SearchEngine, make_server
+from .compile_cache import enable_compile_cache
 
 
 def build_or_load_index(args) -> tuple[api.VectorIndex, np.ndarray]:
@@ -145,6 +146,7 @@ def main(argv: Optional[list[str]] = None) -> int:
     ap.add_argument("--cache-size", type=int, default=1024,
                     help="LRU result-cache entries (0 disables)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print(f"[1/5] corpus: {args.n} x {args.dim}")
     index, corpus = build_or_load_index(args)

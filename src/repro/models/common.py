@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..distributed.partitioning import MeshAxes, default_rules, spec_for, usable_axes
 
@@ -90,8 +89,8 @@ def sp_all_gather(x: jax.Array, ctx: "MeshCtx") -> jax.Array:
     def f(xl):
         return jax.lax.all_gather(xl, "model", axis=1, tiled=True)
 
-    return shard_map(f, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-                     check_rep=False)(x)
+    return jax.shard_map(f, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=out_spec, check_vma=False)(x)
 
 
 def row_parallel_out_proj(x: jax.Array, w: jax.Array, ctx: "MeshCtx",
@@ -120,27 +119,8 @@ def row_parallel_out_proj(x: jax.Array, w: jax.Array, ctx: "MeshCtx",
         return jax.lax.psum_scatter(part.astype(xl.dtype), "model",
                                     scatter_dimension=1, tiled=True)
 
-    return shard_map(f, mesh=mesh, in_specs=(x_spec, w_spec),
-                     out_specs=out_spec, check_rep=False)(x, w)
-
-
-# optimization_barrier has no differentiation rule on older jax (< 0.5);
-# this custom_vjp applies the barrier on both the primal and the cotangent,
-# which is also what newer jax's built-in rule does.
-@jax.custom_vjp
-def opt_barrier(x: jax.Array) -> jax.Array:
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return opt_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
+    return jax.shard_map(f, mesh=mesh, in_specs=(x_spec, w_spec),
+                         out_specs=out_spec, check_vma=False)(x, w)
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -224,8 +204,8 @@ def sharded_embedding_lookup(
                                         tiled=True)
         return jax.lax.psum(out, row_axes)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(table_spec, ids_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(table_spec, ids_spec),
+                       out_specs=out_spec, check_vma=False)
     return fn(table, ids)
 
 
@@ -274,9 +254,9 @@ def embedding_bag(
                                  mask.astype(e.dtype))
             return jax.lax.psum(partial, row_axes)  # [B_loc, d] only
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(table_spec, ids_spec, len_spec),
-                       out_specs=out_spec, check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(table_spec, ids_spec, len_spec),
+                           out_specs=out_spec, check_vma=False)
         s = fn(table, ids, lengths)
     if mode == "sum":
         return s

@@ -24,7 +24,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from ...configs.base import GNNConfig, ShapeCell
 from ...distributed.partitioning import ParamDef, init_from_schema
@@ -94,8 +93,8 @@ def mean_aggregate(h: jax.Array, src: jax.Array, dst: jax.Array,
             return out
         return out / jnp.maximum(deg, 1.0)[:, None]
 
-    fn = shard_map(f, mesh=mesh, in_specs=(h_spec, e_spec, e_spec),
-                   out_specs=h_spec, check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(h_spec, e_spec, e_spec),
+                       out_specs=h_spec, check_vma=False)
     return fn(h, src, dst)
 
 

@@ -32,7 +32,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from ..common import MeshCtx, NULL_CTX
 
@@ -245,10 +244,10 @@ def decode_attention(
         return out, k2, v2
 
     qg_spec = ctx.pspec(qg.shape, "batch", None, None, None)
-    fn_sm = shard_map(
+    fn_sm = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(qg_spec, c_spec, c_spec, n_spec, n_spec, ctx.pspec(())),
-        out_specs=(qg_spec, c_spec, c_spec), check_rep=False)
+        out_specs=(qg_spec, c_spec, c_spec), check_vma=False)
     out, k2, v2 = fn_sm(qg, k_cache, v_cache, k_new, v_new, cur_len)
     return out.reshape(b, h, dh).astype(q.dtype), k2, v2
 

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from ...configs.base import TransformerConfig
 from ...distributed.partitioning import (ParamDef, abstract_from_schema,
                                          init_from_schema)
-from ..common import (MeshCtx, NULL_CTX, opt_barrier, pad_to_multiple,
+from ..common import (MeshCtx, NULL_CTX, pad_to_multiple,
                       rms_norm, row_parallel_out_proj,
                       sharded_embedding_lookup, sp_all_gather)
 from . import attention as attn_lib
@@ -136,7 +136,7 @@ def decoder_layer(x, lp, cfg: TransformerConfig, ctx: MeshCtx, scheme: str,
     # Barrier: without it XLA hoists the rms_norm bf16->f32 convert of the
     # *saved residual stack* out of the backward while loop, materializing a
     # full-precision [L, B, S, d] copy (+6 GiB/dev on qwen3-235B).
-    x = opt_barrier(x)
+    x = jax.lax.optimization_barrier(x)
     b, s, d = x.shape
     cdt = jnp.dtype(cfg.compute_dtype)
     h, kh = effective_heads(cfg, ctx)

@@ -21,7 +21,6 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from ...configs.base import TransformerConfig
 from ..common import MeshCtx
@@ -159,12 +158,12 @@ def moe_block(
         return recv, route.slot, route.gates, aux_tok
 
     aux_spec = ctx.pspec((t_global,), "tokens")
-    disp = shard_map(
+    disp = jax.shard_map(
         dispatch, mesh=mesh,
         in_specs=(tok_spec, ctx.pspec(router_w.shape, None, None)),
         out_specs=(buf_spec, slot_spec, slot_spec,
                    {k_: aux_spec for k_ in aux_keys}),
-        check_rep=False)
+        check_vma=False)
     buf, slot, gates, aux_tok = disp(x, router_w)
 
     # expert GEMMs under GSPMD: E sharded over model; FSDP d gathered on use
@@ -187,10 +186,10 @@ def moe_block(
                                         tiled=True)  # [E, cap, d]
         return _combine_local(y_full, slot_l, gates_l, slot_l.shape[0])
 
-    comb = shard_map(
+    comb = jax.shard_map(
         combine, mesh=mesh,
         in_specs=(buf_spec, slot_spec, slot_spec),
-        out_specs=tok_spec, check_rep=False)
+        out_specs=tok_spec, check_vma=False)
     y = comb(y_buf, slot, gates)
     aux = {k_: v.mean() for k_, v in aux_tok.items()}
     return y.astype(compute_dtype), aux

@@ -26,10 +26,12 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from ..distributed.partitioning import _flat_axes
-from ..kernels.common import NEG_INF, PAD_ID
+from ..kernels.common import EXACT, NEG_INF, PAD_ID
 from ..kernels.topk_merge.ops import topk_merge
 from ..models.common import MeshCtx
 
@@ -104,8 +106,8 @@ def distributed_topk(scores: jax.Array, k: int, ctx: MeshCtx,
         vg, ig = topk_merge(vs[None, :], gis[None, :], k)
         return vg[0], ig[0]
 
-    fn = shard_map(f, mesh=mesh, in_specs=(s_spec,),
-                   out_specs=(r_spec, r_spec), check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(s_spec,),
+                       out_specs=(r_spec, r_spec), check_vma=False)
     return fn(scores)
 
 
@@ -118,26 +120,50 @@ def sharded_scores(queries: jax.Array, db: jax.Array, metric: str,
     if metric == "cosine":
         qn = q32 / jnp.maximum(jnp.linalg.norm(q32, -1, keepdims=True), 1e-12)
         dn = d32 / jnp.maximum(jnp.linalg.norm(d32, -1, keepdims=True), 1e-12)
-        s = qn @ dn.T
+        s = jnp.matmul(qn, dn.T, precision=EXACT)
     elif metric == "euclidean":
         q2 = jnp.sum(q32 * q32, -1)[:, None]
         d2 = jnp.sum(d32 * d32, -1)[None, :]
-        s = -(q2 - 2.0 * q32 @ d32.T + d2)  # negative squared distance
+        s = -(q2 - 2.0 * jnp.matmul(q32, d32.T, precision=EXACT)
+              + d2)  # negative squared distance
     else:
         raise ValueError(metric)
     return ctx.constrain(s, None, "db_rows")
 
 
+def place_rows(x: np.ndarray, ctx: MeshCtx) -> jax.Array:
+    """Put host rows [N, d] on the device(s). With a mesh the rows go
+    row-sharded over "db_rows" straight from the host — each device
+    receives only its slab, and the corpus is never staged on one device.
+    A sharded array cannot be ragged, so the last slab is zero-padded to
+    ``n_shards * ceil(N / n_shards)`` rows; :func:`search` takes the real
+    row count ``n`` and pins the pad rows."""
+    axes, n_shards = _shard_axes(ctx, "db_rows")
+    if n_shards == 1:
+        return jnp.asarray(x, jnp.float32)
+    n, d = x.shape
+    n_loc = -(-n // n_shards)
+
+    def slab(idx):
+        rows = x[idx[0].start:min(idx[0].stop, n)]
+        return np.pad(rows, ((0, n_loc - rows.shape[0]), (0, 0)))
+
+    return jax.make_array_from_callback(
+        (n_loc * n_shards, d), NamedSharding(ctx.mesh, P(axes, None)), slab)
+
+
 def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
-           metric: str = "euclidean", alive: jax.Array | None = None
-           ) -> tuple[jax.Array, jax.Array]:
+           metric: str = "euclidean", alive: jax.Array | None = None,
+           n: int | None = None) -> tuple[jax.Array, jax.Array]:
     """Exact k-NN: returns (scores [Q, k], indices [Q, k]).
 
     ``alive`` (bool [N]) tombstones db rows: a dead row is pinned to
     ``(NEG_INF, PAD_ID)`` before the local top-k on every shard, so it can
     never surface — same contract as ``l2_topk``'s ``db_mask`` operand.
-    ``alive=None`` leaves the static path bitwise untouched."""
-    n = db.shape[0]
+    ``alive=None`` leaves the static path bitwise untouched. ``n`` is the
+    real row count of a ``db`` that :func:`place_rows` padded (default:
+    every row is real)."""
+    n = db.shape[0] if n is None else n
     axes, n_shards = _shard_axes(ctx, "db_rows")
     if n_shards == 1:
         s = sharded_scores(queries, db, metric, ctx)
@@ -151,8 +177,8 @@ def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
     mesh = ctx.mesh
     n_loc = -(-n // n_shards)           # ceil: last shard may be ragged
     n_pad = n_loc * n_shards
-    if n_pad > n:
-        db = jnp.pad(db, ((0, n_pad - n), (0, 0)))
+    if n_pad > db.shape[0]:
+        db = jnp.pad(db, ((0, n_pad - db.shape[0]), (0, 0)))
     if alive is not None and n_pad > alive.shape[0]:
         alive = jnp.pad(alive, (0, n_pad - alive.shape[0]))
     kl = min(k, n_loc)
@@ -190,6 +216,6 @@ def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
     if alive is not None:
         in_specs += (ctx.pspec((n_pad,), "db_rows"),)
         args += (alive,)
-    fn = shard_map(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=(out_spec, out_spec), check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                       out_specs=(out_spec, out_spec), check_vma=False)
     return fn(*args)

@@ -18,7 +18,7 @@ Search greedy-descends from the entry point through the upper layers
 engines share those semantics:
 
 * :func:`search` — the sequential reference: per-query pointer-chasing on
-  host (numpy + heapq), one ``candidate_distances`` dispatch per hop.
+  host (numpy + heapq), one ``candidate_distances`` call per hop.
 * :func:`search_batched` — the array-native serving path: a batched
   frontier loop over the :meth:`HNSWGraph.pack`-ed dense adjacency. Per
   hop it pops the best unexpanded beam entry of EVERY live query at once,
@@ -57,43 +57,25 @@ import numpy as np
 _MAX_LEVEL = 15
 
 
-def _backend() -> str:
+def batched_driver(impl: str = "auto") -> str:
+    """The :func:`search_batched` driver ``impl`` names: ``"auto"`` is the
+    Pallas-hop traversal (``"fused"``) on TPU and the host loop (``"np"``)
+    elsewhere."""
+    if impl != "auto":
+        return impl
     import jax
 
-    return jax.default_backend()
+    return "fused" if jax.default_backend() == "tpu" else "np"
 
 
-def _resolve_impl(impl: str) -> str:
-    """Collapse ``"auto"`` to a concrete impl ONCE per build/search — the
-    backend cannot change mid-traversal and the hot loops issue tens of
-    thousands of tiny distance batches."""
-    if impl == "auto":
-        return "fused" if _backend() == "tpu" else "np"
-    return impl
-
-
-def candidate_distances(q: np.ndarray, vecs: np.ndarray,
-                        impl: str = "auto") -> np.ndarray:
-    """Squared L2 from one query [d] to a candidate batch [c, d].
-
-    ``impl="fused"`` routes through the fused ``l2_topk`` scan (Pallas on
-    TPU, jnp ref elsewhere) with k = c and scatters the sorted output back
-    to input order; ``"np"`` is the host ref. ``"auto"`` picks fused only
-    on TPU — traversal is host-driven, so device round-trips lose on CPU.
-    """
-    impl = _resolve_impl(impl)
-    if impl == "np":
-        diff = vecs - q
-        return np.einsum("cd,cd->c", diff, diff)
-    import jax.numpy as jnp
-
-    from ..kernels import l2_topk
-
-    c = int(vecs.shape[0])
-    scores, idx = l2_topk(jnp.asarray(q)[None, :], jnp.asarray(vecs), c)
-    out = np.empty(c, np.float32)
-    out[np.asarray(idx[0])] = -np.asarray(scores[0])  # scores = -||q-d||^2
-    return out
+def candidate_distances(q: np.ndarray, vecs: np.ndarray) -> np.ndarray:
+    """Squared L2 from one query [d] to a candidate batch [c, d], on the
+    host. Build, insert and the sequential engine call this once per beam
+    step with a few dozen candidates; a device dispatch per call (with a
+    compile per distinct candidate count) costs far more than the
+    arithmetic, so these loops score host arrays on every backend."""
+    diff = vecs - q
+    return np.einsum("cd,cd->c", diff, diff)
 
 
 class _Evals:
@@ -295,7 +277,7 @@ def sample_levels(n: int, M: int, seed: int) -> np.ndarray:
     return np.minimum(lv, _MAX_LEVEL)
 
 
-def _greedy_descent(vecs, adj, q, cur, d_cur, evals, impl, alive=None):
+def _greedy_descent(vecs, adj, q, cur, d_cur, evals, alive=None):
     """ef=1 layer traversal: hop to the closest neighbor until no
     neighbor improves. ``alive`` (bool [N]) hides tombstoned nodes: a
     dead neighbor is never hopped to (``alive=None`` = all alive)."""
@@ -306,7 +288,7 @@ def _greedy_descent(vecs, adj, q, cur, d_cur, evals, impl, alive=None):
             nbrs = nbrs[alive[nbrs]]
         if nbrs.size == 0:
             return cur, d_cur
-        ds = candidate_distances(q, vecs[nbrs], impl)
+        ds = candidate_distances(q, vecs[nbrs])
         evals.n += int(nbrs.size)
         j = int(np.argmin(ds))
         if ds[j] >= d_cur:
@@ -314,8 +296,7 @@ def _greedy_descent(vecs, adj, q, cur, d_cur, evals, impl, alive=None):
         cur, d_cur = int(nbrs[j]), float(ds[j])
 
 
-def _search_layer(vecs, adj, q, eps, ef, visited, stamp, evals, impl,
-                  alive=None):
+def _search_layer(vecs, adj, q, eps, ef, visited, stamp, evals, alive=None):
     """Best-first beam (Alg. 2): returns the ef closest visited nodes as a
     sorted [(dist, node), ...] list. ``eps`` are (dist, node) entry points
     (already counted); ``visited``/``stamp`` implement an O(1)-reset
@@ -340,7 +321,7 @@ def _search_layer(vecs, adj, q, eps, ef, visited, stamp, evals, impl,
         if fresh.size == 0:
             continue
         visited[fresh] = stamp
-        ds = candidate_distances(q, vecs[fresh], impl)
+        ds = candidate_distances(q, vecs[fresh])
         evals.n += int(fresh.size)
         worst = -res[0][0]
         full = len(res) >= ef
@@ -355,7 +336,7 @@ def _search_layer(vecs, adj, q, eps, ef, visited, stamp, evals, impl,
     return sorted((-nd, node) for nd, node in res)
 
 
-def _select_heuristic(cands, vecs, m, evals, impl, keep_pruned=False):
+def _select_heuristic(cands, vecs, m, evals, keep_pruned=False):
     """Alg. 4 neighbor selection: scan candidates nearest-first, keep one
     only if it is closer to the query than to every kept neighbor. With
     ``keep_pruned`` the remaining slots are refilled nearest-first (used
@@ -367,7 +348,7 @@ def _select_heuristic(cands, vecs, m, evals, impl, keep_pruned=False):
         if len(sel) >= m:
             break
         if sel:
-            ds = candidate_distances(vecs[c], np.stack(sel_vecs), impl)
+            ds = candidate_distances(vecs[c], np.stack(sel_vecs))
             evals.n += len(sel)
             if not np.all(d_c < ds):
                 pruned.append(c)
@@ -393,18 +374,18 @@ def _bfs_layer0(links0: np.ndarray, entry: int) -> np.ndarray:
     return seen
 
 
-def _evict_farthest(links0, vecs, node, evals, impl) -> None:
+def _evict_farthest(links0, vecs, node, evals) -> None:
     """Free one slot in a full row by dropping its farthest link (both
     directions, keeping the graph symmetric)."""
     nbrs = links0[node][links0[node] >= 0]
-    ds = candidate_distances(vecs[node], vecs[nbrs], impl)
+    ds = candidate_distances(vecs[node], vecs[nbrs])
     evals.n += int(nbrs.size)
     t = int(nbrs[np.argmax(ds)])
     links0[t][links0[t] == node] = -1
     links0[node][links0[node] == t] = -1
 
 
-def _repair_connectivity(vecs, links0, entry, evals, impl) -> int:
+def _repair_connectivity(vecs, links0, entry, evals) -> int:
     """Symmetric pruning can (rarely) strand a node at layer 0: every
     neighbor that once pointed at it overflowed and evicted it. Stitch each
     stranded component back via its nearest reachable node — an evictee
@@ -418,12 +399,12 @@ def _repair_connectivity(vecs, links0, entry, evals, impl) -> int:
             return stitched
         u = int(miss[0])
         reach = np.flatnonzero(seen)
-        ds = candidate_distances(vecs[u], vecs[reach], impl)
+        ds = candidate_distances(vecs[u], vecs[reach])
         evals.n += int(reach.size)
         r = int(reach[np.argmin(ds)])
         for node in (u, r):
             if not np.any(links0[node] < 0):
-                _evict_farthest(links0, vecs, node, evals, impl)
+                _evict_farthest(links0, vecs, node, evals)
         links0[u][np.flatnonzero(links0[u] < 0)[0]] = r
         links0[r][np.flatnonzero(links0[r] < 0)[0]] = u
         stitched += 1
@@ -437,7 +418,7 @@ def _write_row(adj, node, nbrs):
 
 
 def _insert_node(vecs, levels, links0, links, M, m0, top, i, entry,
-                 ef_construction, visited, evals, impl) -> int:
+                 ef_construction, visited, evals) -> int:
     """Insert node ``i`` (Alg. 1 body, shared verbatim between
     :func:`build` and :func:`insert_batch`): greedy-descend the upper
     layers, beam + heuristic-select per layer, write bidirectional links
@@ -446,19 +427,18 @@ def _insert_node(vecs, levels, links0, links, M, m0, top, i, entry,
     l_i = int(levels[i])
     l_ep = int(levels[entry])
     cur = entry
-    d_cur = float(candidate_distances(q, vecs[entry][None], impl)[0])
+    d_cur = float(candidate_distances(q, vecs[entry][None])[0])
     evals.n += 1
     for layer in range(l_ep, l_i, -1):
         cur, d_cur = _greedy_descent(vecs, links[layer - 1], q, cur,
-                                     d_cur, evals, impl)
+                                     d_cur, evals)
     eps = [(d_cur, cur)]
     for layer in range(min(l_ep, l_i), -1, -1):
         adj = links0 if layer == 0 else links[layer - 1]
         cap = m0 if layer == 0 else M
         found = _search_layer(vecs, adj, q, eps, ef_construction,
-                              visited, i * (top + 1) + layer, evals,
-                              impl)
-        sel = _select_heuristic(found, vecs, M, evals, impl)
+                              visited, i * (top + 1) + layer, evals)
+        sel = _select_heuristic(found, vecs, M, evals)
         _write_row(adj, i, sel)
         # bidirectional: add the back-link, re-pruning on overflow and
         # dropping the reverse edge of anything the prune evicts
@@ -469,13 +449,13 @@ def _insert_node(vecs, levels, links0, links, M, m0, top, i, entry,
                 row[free[0]] = i
                 continue
             nbrs = row[row >= 0]
-            ds = candidate_distances(vecs[s], vecs[nbrs], impl)
+            ds = candidate_distances(vecs[s], vecs[nbrs])
             evals.n += int(nbrs.size)
-            d_i = float(candidate_distances(vecs[s], q[None], impl)[0])
+            d_i = float(candidate_distances(vecs[s], q[None])[0])
             evals.n += 1
             merged = sorted([*zip(ds.tolist(), nbrs.tolist()),
                              (d_i, i)])
-            kept = _select_heuristic(merged, vecs, cap, evals, impl,
+            kept = _select_heuristic(merged, vecs, cap, evals,
                                      keep_pruned=True)
             for t in nbrs:
                 if t not in kept:
@@ -502,13 +482,12 @@ def _compact_pads(links0, links) -> None:
 
 
 def build(corpus: np.ndarray, M: int = 32, ef_construction: int = 100,
-          seed: int = 0, impl: str = "auto") -> HNSWGraph:
+          seed: int = 0) -> HNSWGraph:
     """Sequential heuristic insert of every corpus row (Alg. 1)."""
     vecs = np.ascontiguousarray(np.asarray(corpus, np.float32))
     n = vecs.shape[0]
     if n == 0:
         raise ValueError("empty corpus")
-    impl = _resolve_impl(impl)
     m0 = 2 * M
     levels = sample_levels(n, M, seed)
     top = int(levels.max())
@@ -521,16 +500,15 @@ def build(corpus: np.ndarray, M: int = 32, ef_construction: int = 100,
     entry = 0
     for i in range(1, n):
         entry = _insert_node(vecs, levels, links0, links, M, m0, top, i,
-                             entry, ef_construction, visited, evals, impl)
-    _repair_connectivity(vecs, links0, entry, evals, impl)
+                             entry, ef_construction, visited, evals)
+    _repair_connectivity(vecs, links0, entry, evals)
     _compact_pads(links0, links)
     return HNSWGraph(vecs=vecs, levels=levels, links0=links0, links=links,
                      entry=entry, M=M)
 
 
 def insert_batch(graph: HNSWGraph, new_vecs: np.ndarray,
-                 ef_construction: int = 100, seed: int = 0,
-                 impl: str = "auto") -> np.ndarray:
+                 ef_construction: int = 100, seed: int = 0) -> np.ndarray:
     """Incremental insert: append ``new_vecs`` rows to a built graph with
     the SAME per-node machinery as :func:`build` (greedy descent, beam,
     heuristic selection, bidirectional overflow re-pruning), in place.
@@ -555,7 +533,6 @@ def insert_batch(graph: HNSWGraph, new_vecs: np.ndarray,
                          f" vectors, got {nv.shape}")
     if b == 0:
         return np.zeros(0, np.int64)
-    impl = _resolve_impl(impl)
     n0 = graph.ntotal
     M, m0 = graph.M, 2 * graph.M
     new_levels = sample_levels(b, M, seed + n0)
@@ -573,8 +550,8 @@ def insert_batch(graph: HNSWGraph, new_vecs: np.ndarray,
     entry = graph.entry
     for i in range(n0, n0 + b):
         entry = _insert_node(vecs, levels, links0, links, M, m0, top, i,
-                             entry, ef_construction, visited, evals, impl)
-    _repair_connectivity(vecs, links0, entry, evals, impl)
+                             entry, ef_construction, visited, evals)
+    _repair_connectivity(vecs, links0, entry, evals)
     _compact_pads(links0, links)
     graph.vecs = vecs
     graph.levels = levels
@@ -621,8 +598,7 @@ def reassign_entry(graph: HNSWGraph, alive: np.ndarray) -> int:
 
 
 def search(graph: HNSWGraph, queries: np.ndarray, k: int,
-           ef_search: int = 64, impl: str = "auto",
-           alive: Optional[np.ndarray] = None
+           ef_search: int = 64, alive: Optional[np.ndarray] = None
            ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Beam search per query. Returns (scores [Q, k], ids [Q, k], evals
     [Q]): scores = -squared-euclidean (engine convention, higher =
@@ -633,7 +609,6 @@ def search(graph: HNSWGraph, queries: np.ndarray, k: int,
     must point at an alive node (:func:`reassign_entry`)."""
     q = np.asarray(queries, np.float32)
     nq = q.shape[0]
-    impl = _resolve_impl(impl)
     if alive is not None:
         alive = np.asarray(alive, bool)
         if not alive[graph.entry]:
@@ -647,16 +622,13 @@ def search(graph: HNSWGraph, queries: np.ndarray, k: int,
     for qi in range(nq):
         cnt = _Evals()
         cur = graph.entry
-        d_cur = float(candidate_distances(q[qi], graph.vecs[cur][None],
-                                          impl)[0])
+        d_cur = float(candidate_distances(q[qi], graph.vecs[cur][None])[0])
         cnt.n += 1
         for layer in range(graph.max_level, 0, -1):
             cur, d_cur = _greedy_descent(graph.vecs, graph.links[layer - 1],
-                                         q[qi], cur, d_cur, cnt, impl,
-                                         alive)
+                                         q[qi], cur, d_cur, cnt, alive)
         found = _search_layer(graph.vecs, graph.links0, q[qi],
-                              [(d_cur, cur)], ef, visited, qi, cnt, impl,
-                              alive)
+                              [(d_cur, cur)], ef, visited, qi, cnt, alive)
         for j, (d, node) in enumerate(found[:k]):
             scores[qi, j] = -d
             ids[qi, j] = node
@@ -724,8 +696,7 @@ def search_batched(graph: HNSWGraph, queries: np.ndarray, k: int,
     if nq == 0:
         return (np.zeros((0, k), np.float32), np.zeros((0, k), np.int32),
                 np.zeros(0, np.int64), 0)
-    if impl == "auto":
-        impl = "fused" if _backend() == "tpu" else "np"
+    impl = batched_driver(impl)
     if alive is not None:
         alive = np.asarray(alive, bool)
         if not alive[graph.entry]:
@@ -957,7 +928,9 @@ def _traverse_impl(q, vecs, vecs_sq, nbrs0, upper, entry, codes, node_bias,
     import jax
     import jax.numpy as jnp
 
-    from ..kernels.graph_beam.kernel import NEG_INF, graph_beam_pallas
+    from ..kernels.common import EXACT
+    from ..kernels.graph_beam.kernel import (NEG_INF, gather_layout,
+                                             graph_beam_pallas)
     from ..kernels.graph_beam_q.kernel import graph_beam_q_pallas
 
     nq = q.shape[0]
@@ -968,7 +941,8 @@ def _traverse_impl(q, vecs, vecs_sq, nbrs0, upper, entry, codes, node_bias,
 
     if mode == "sq8":
         q_op = (2.0 * q * c1[None, :]).astype(jnp.float32)
-        q_bias = (2.0 * (q @ c0) - q_sq).astype(jnp.float32)
+        q_bias = (2.0 * jnp.matmul(q, c0, precision=EXACT)
+                  - q_sq).astype(jnp.float32)
     elif mode == "pq":
         from ..search.quantize import adc_lut  # the ONE LUT formula home
 
@@ -990,11 +964,13 @@ def _traverse_impl(q, vecs, vecs_sq, nbrs0, upper, entry, codes, node_bias,
         safe = jnp.where(cand >= 0, cand, 0)
         if mode == "f32":
             g = vecs[safe]                                   # [Q, W, d]
-            s = (2.0 * jnp.einsum("qwd,qd->qw", g, q) - vecs_sq[safe]
+            s = (2.0 * jnp.einsum("qwd,qd->qw", g, q, precision=EXACT)
+                 - vecs_sq[safe]
                  - q_sq[:, None])
         elif mode == "sq8":
             g = codes[safe].astype(jnp.float32)              # [Q, W, d]
-            s = (jnp.einsum("qwd,qd->qw", g, q_op) + q_bias[:, None]
+            s = (jnp.einsum("qwd,qd->qw", g, q_op, precision=EXACT)
+                 + q_bias[:, None]
                  - node_bias[safe])
         else:
             m = codes.shape[1]
@@ -1053,6 +1029,12 @@ def _traverse_impl(q, vecs, vecs_sq, nbrs0, upper, entry, codes, node_bias,
         safe_b = jnp.where(in_beam, beam_i, 0)
         return in_beam & (jnp.take_along_axis(state, safe_b, axis=1) == 1)
 
+    if use_pallas:
+        # the hop kernel's tile layout of what it gathers, built once here
+        # and not per hop (a pad inside the loop body would copy per hop)
+        hop_rows, hop_bias = gather_layout(
+            *((vecs, vecs_sq) if mode == "f32" else (codes, node_bias)))
+
     def hop_body(c):
         beam_v, beam_i, state, evals, hops = c
         unexp = unexpanded(beam_i, state)
@@ -1074,10 +1056,10 @@ def _traverse_impl(q, vecs, vecs_sq, nbrs0, upper, entry, codes, node_bias,
         if not use_pallas:
             nv, ni = merge_jnp(beam_v, beam_i, cand, ef)
         elif mode == "f32":
-            nv, ni = graph_beam_pallas(q, vecs, vecs_sq, cand,
+            nv, ni = graph_beam_pallas(q, hop_rows, hop_bias, cand,
                                        beam_v, beam_i)
         else:
-            nv, ni = graph_beam_q_pallas(q_op, q_bias, codes, node_bias,
+            nv, ni = graph_beam_q_pallas(q_op, q_bias, hop_rows, hop_bias,
                                          cand, beam_v, beam_i, mode=mode,
                                          ksub=ksub)
         return nv, ni, state, evals, hops + 1

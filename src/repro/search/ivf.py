@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.common import EXACT
+
 
 @dataclass
 class IVFIndex:
@@ -37,9 +39,12 @@ def kmeans(x: jax.Array, n_clusters: int, iters: int = 10,
     idx = jax.random.choice(key, n, (n_clusters,), replace=False)
     cent = x[idx]
 
+    # x is an argument, not a closure: a closed-over array is baked into
+    # the program as a constant (the whole corpus) and constant-folded
     @jax.jit
-    def step(cent):
-        d2 = (jnp.sum(x * x, 1)[:, None] - 2 * x @ cent.T
+    def step(cent, x):
+        d2 = (jnp.sum(x * x, 1)[:, None]
+              - 2 * jnp.matmul(x, cent.T, precision=EXACT)
               + jnp.sum(cent * cent, 1)[None, :])
         assign = jnp.argmin(d2, 1)
         sums = jax.ops.segment_sum(x, assign, num_segments=n_clusters)
@@ -51,7 +56,7 @@ def kmeans(x: jax.Array, n_clusters: int, iters: int = 10,
 
     assign = None
     for _ in range(iters):
-        cent, assign = step(cent)
+        cent, assign = step(cent, x)
     return cent, assign
 
 
@@ -89,13 +94,14 @@ def search(index: IVFIndex, queries: jax.Array, k: int, nprobe: int = 8
     corpus row ids [Q, k]); scores = -squared-euclidean (higher = closer)."""
     q = jnp.asarray(queries, jnp.float32)
     cent = index.centroids
-    d2c = (jnp.sum(q * q, 1)[:, None] - 2 * q @ cent.T
+    d2c = (jnp.sum(q * q, 1)[:, None]
+           - 2 * jnp.matmul(q, cent.T, precision=EXACT)
            + jnp.sum(cent * cent, 1)[None, :])
     _, cells = jax.lax.top_k(-d2c, nprobe)          # [Q, P]
     vecs = index.list_vecs[cells]                   # [Q, P, cap, d]
     ids = index.lists[cells]                        # [Q, P, cap]
     mask = index.list_mask[cells]
-    s = (2.0 * jnp.einsum("qd,qpcd->qpc", q, vecs)
+    s = (2.0 * jnp.einsum("qd,qpcd->qpc", q, vecs, precision=EXACT)
          - jnp.sum(vecs * vecs, -1)
          - jnp.sum(q * q, -1)[:, None, None])
     s = jnp.where(mask, s, -jnp.inf)

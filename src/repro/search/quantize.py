@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.common import EXACT
 from .ivf import kmeans
 
 
@@ -85,8 +86,9 @@ def sq8_scan(vmin: jax.Array, step: jax.Array, q: jax.Array,
     q = jnp.asarray(q, jnp.float32)
     cf = codes.astype(jnp.float32)                     # [N, d]
     # q . x_hat = q . vmin + (q * step) . codes
-    qdotmin = q @ vmin                                 # [Q]
-    qdotc = (q * step[None, :]) @ cf.T                 # [Q, N]
+    qdotmin = jnp.matmul(q, vmin, precision=EXACT)     # [Q]
+    qdotc = jnp.matmul(q * step[None, :], cf.T,
+                       precision=EXACT)                # [Q, N]
     s = (2.0 * (qdotmin[:, None] + qdotc)
          - recon_sq[None, :]
          - jnp.sum(q * q, axis=-1, keepdims=True))
@@ -104,15 +106,17 @@ def ivf_sq8_search(centroids: jax.Array, lists: jax.Array, codes: jax.Array,
     as in :class:`repro.search.ivf.IVFIndex`. Same -1/-inf pad semantics.
     """
     q = jnp.asarray(q, jnp.float32)
-    d2c = (jnp.sum(q * q, 1)[:, None] - 2 * q @ centroids.T
+    d2c = (jnp.sum(q * q, 1)[:, None]
+           - 2 * jnp.matmul(q, centroids.T, precision=EXACT)
            + jnp.sum(centroids * centroids, 1)[None, :])
     _, cells = jax.lax.top_k(-d2c, nprobe)             # [Q, P]
     cf = codes[cells].astype(jnp.float32)              # [Q, P, cap, d]
     ids = lists[cells]                                 # [Q, P, cap]
     m = mask[cells]
     r2 = recon_sq[cells]                               # [Q, P, cap]
-    qdotmin = q @ vmin                                 # [Q]
-    qdotc = jnp.einsum("qd,qpcd->qpc", q * step[None, :], cf)
+    qdotmin = jnp.matmul(q, vmin, precision=EXACT)     # [Q]
+    qdotc = jnp.einsum("qd,qpcd->qpc", q * step[None, :], cf,
+                       precision=EXACT)
     s = (2.0 * (qdotmin[:, None, None] + qdotc)
          - r2 - jnp.sum(q * q, -1)[:, None, None])
     s = jnp.where(m, s, -jnp.inf)
@@ -172,7 +176,7 @@ def pq_encode(pq: ProductQuantizer, x: jax.Array) -> jax.Array:
     # d2[n, m, j] = ||x_sub - cb||^2 ; argmin over j
     cb = pq.codebooks
     d2 = (jnp.sum(xs * xs, -1)[:, :, None]
-          - 2 * jnp.einsum("nms,mjs->nmj", xs, cb)
+          - 2 * jnp.einsum("nms,mjs->nmj", xs, cb, precision=EXACT)
           + jnp.sum(cb * cb, -1)[None, :, :])
     return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
 
@@ -195,7 +199,8 @@ def adc_lut(codebooks: jax.Array, q: jax.Array) -> jax.Array:
     m, _, dsub = codebooks.shape
     qs = q.reshape(q.shape[0], m, dsub)
     return (jnp.sum(qs * qs, -1)[:, :, None]
-            - 2 * jnp.einsum("qms,mjs->qmj", qs, codebooks)
+            - 2 * jnp.einsum("qms,mjs->qmj", qs, codebooks,
+                             precision=EXACT)
             + jnp.sum(codebooks * codebooks, -1)[None, :, :])
 
 
@@ -231,7 +236,8 @@ def ivf_pq_search(centroids: jax.Array, lists: jax.Array, codes: jax.Array,
     """
     q = jnp.asarray(q, jnp.float32)
     m, ksub, _ = codebooks.shape
-    d2c = (jnp.sum(q * q, 1)[:, None] - 2 * q @ centroids.T
+    d2c = (jnp.sum(q * q, 1)[:, None]
+           - 2 * jnp.matmul(q, centroids.T, precision=EXACT)
            + jnp.sum(centroids * centroids, 1)[None, :])
     _, cells = jax.lax.top_k(-d2c, nprobe)             # [Q, P]
     ids = lists[cells]                                 # [Q, P, cap]

@@ -138,15 +138,15 @@ def test_search_pads_when_beam_short(corpus):
     assert np.all(np.isfinite(scores[valid]))
 
 
-def test_candidate_distances_fused_matches_np():
-    """The TPU-routed form (fused kernel; jnp ref off-TPU) must equal the
-    host ref, scattered back to input order."""
+def test_candidate_distances_matches_brute_force():
+    """The host scorer of build/insert/sequential search returns squared
+    L2 in input order (float64 brute force as the reference)."""
     rng = np.random.default_rng(7)
     q = rng.standard_normal(24).astype(np.float32)
     vecs = rng.standard_normal((33, 24)).astype(np.float32)
-    a = hnsw.candidate_distances(q, vecs, impl="np")
-    b = hnsw.candidate_distances(q, vecs, impl="fused")
-    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    want = ((vecs.astype(np.float64) - q) ** 2).sum(axis=1)
+    np.testing.assert_allclose(hnsw.candidate_distances(q, vecs), want,
+                               rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -227,11 +227,10 @@ needs_mesh = pytest.mark.skipif(
 @pytest.mark.slow
 @needs_mesh
 def test_mesh_search_matches_flat_ragged():
-    from repro.launch.mesh import make_mesh
     from repro.models.common import MeshCtx
     from repro.search.distributed import search as dist_search
 
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     ctx = MeshCtx(mesh=mesh, rules={"db_rows": ("data",)})
     corpus = _int_corpus(101, 16, seed=21)  # ragged: 101 % 8 != 0
     q = _queries(7, 16, seed=22)
@@ -244,11 +243,10 @@ def test_mesh_search_matches_flat_ragged():
 @pytest.mark.slow
 @needs_mesh
 def test_mesh_distributed_topk_small_shards():
-    from repro.launch.mesh import make_mesh
     from repro.models.common import MeshCtx
     from repro.search.distributed import distributed_topk
 
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     ctx = MeshCtx(mesh=mesh, rules={"db_rows": ("data",)})
     rng = np.random.default_rng(23)
     scores = jnp.asarray(rng.integers(-100, 100, (37,)), jnp.float32)
@@ -262,10 +260,9 @@ def test_mesh_distributed_topk_small_shards():
 @pytest.mark.slow
 @needs_mesh
 def test_mesh_sharded_index_matches_threads():
-    from repro.launch.mesh import make_mesh
     from repro.models.common import MeshCtx
 
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     ctx = MeshCtx(mesh=mesh, rules={"db_rows": ("data",)})
     corpus = _int_corpus(509, 16, seed=24)
     q = _queries(6, 16, seed=25)
@@ -276,3 +273,54 @@ def test_mesh_sharded_index_matches_threads():
                                   np.asarray(threads.indices))
     np.testing.assert_array_equal(np.asarray(meshed.scores),
                                   np.asarray(threads.scores))
+
+
+_PLACE_ROWS_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.api import FlatIndex, index_factory
+from repro.launch.mesh import make_host_mesh
+from repro.models.common import MeshCtx
+
+rng = np.random.default_rng(26)
+corpus = rng.integers(-8, 8, (1001, 16)).astype(np.float32)  # 1001 % 4 != 0
+q = rng.integers(-8, 8, (5, 16)).astype(np.float32)
+ix = index_factory("Shard4,Flat", ctx=MeshCtx(mesh=make_host_mesh()))
+ix.build(corpus)
+db = ix._shards[0]._db
+# row-sharded from the host: each device holds one padded slab, none the whole
+assert db.shape == (1004, 16), db.shape
+assert sorted(s.data.shape[0] for s in db.addressable_shards) == [251] * 4
+assert len({s.device for s in db.addressable_shards}) == 4
+ref = FlatIndex().build(corpus)
+got = ix.search(q, 10)
+np.testing.assert_array_equal(got.indices, ref.search(q, 10).indices)
+assert ix._shards[0].fingerprint() == ref.fingerprint()
+# streaming insert re-places the grown corpus, ids continue after the tail
+extra = rng.integers(-8, 8, (7, 16)).astype(np.float32)
+ix._shards[0].add(extra)
+ref.add(extra)
+assert ix._shards[0].ntotal == 1008 == ref.ntotal
+np.testing.assert_array_equal(ix._shards[0].search(q, 10).indices,
+                              ref.search(q, 10).indices)
+print("PLACE ROWS OK")
+"""
+
+
+@pytest.mark.timeout(300)
+def test_mesh_flat_places_rows_sharded_subprocess():
+    """A mesh FlatIndex puts the corpus on the devices row-sharded from
+    the host (padded to whole slabs, never staged on one device) and
+    answers exactly like the one-device scan, before and after an add.
+    4 forced host devices, so it runs in a subprocess."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", _PLACE_ROWS_SCRIPT], env=env,
+                       cwd=".", capture_output=True, text=True, timeout=280)
+    assert r.returncode == 0, \
+        f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
+    assert "PLACE ROWS OK" in r.stdout

@@ -15,19 +15,19 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.distributed.partitioning import default_rules
 from repro.models.common import MeshCtx, NULL_CTX, sharded_embedding_lookup, embedding_bag
 
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
-                 axis_types=(AxisType.Auto,) * 3)
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 ctx = MeshCtx(mesh=mesh, rules=default_rules(multi_pod=True))
 rng = np.random.default_rng(0)
 
 # --- sharded embedding lookup == local ---
 tbl = jnp.asarray(rng.normal(size=(64, 8)), jnp.float32)
 ids = jnp.asarray(rng.integers(0, 64, (8, 5)), jnp.int32)
-with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
+with mesh:
     out = jax.jit(lambda t, i: sharded_embedding_lookup(
         t, i, ctx, row_logical="table_rows", ids_logical=("batch", None),
         compute_dtype=jnp.float32))(tbl, ids)
