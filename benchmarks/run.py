@@ -292,29 +292,6 @@ def bench_fig1_quick():
          f"kappa={best['kappa']:.2f}")
 
 
-def bench_roofline_summary():
-    if not os.path.exists("results/dryrun.json"):
-        emit("roofline", 0.0, "skipped(no results/dryrun.json)")
-        return
-    from .roofline import build_table
-
-    rows = build_table("results/dryrun.json")
-    single = [r for r in rows if r.mesh == "16x16"]
-    emit("dryrun.cells_compiled", 0.0,
-         f"{len(rows)}/80 across both meshes")
-    for bound in ("compute", "memory", "collective"):
-        n = sum(1 for r in single if r.dominant == bound)
-        emit(f"roofline.single_pod.{bound}_bound_cells", 0.0, f"count={n}")
-    best = max(single, key=lambda r: r.util_vs_dominant)
-    emit("roofline.best_cell", 0.0,
-         f"{best.arch}/{best.shape};util={best.util_vs_dominant:.3f}")
-    tr = [r for r in single if r.shape in ("train_4k",)]
-    for r in tr:
-        emit(f"roofline.{r.arch}.train_4k", 0.0,
-             f"useful_ratio={r.useful_ratio:.2f};bound={r.dominant};"
-             f"peak_gib={r.peak_gib:.1f}")
-
-
 def main() -> None:
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -331,7 +308,6 @@ def main() -> None:
     bench_autotune_quick()
     bench_fig1_quick()
     bench_table1_quick()
-    bench_roofline_summary()
     wall = time.time() - t0
     os.makedirs("results", exist_ok=True)
     json.dump(ROWS, open("results/bench.json", "w"), indent=1)  # legacy path

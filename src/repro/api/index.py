@@ -28,6 +28,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.common import NULL_CTX, MeshCtx
 from ..search import distributed as ds
@@ -384,11 +385,11 @@ class FlatIndex(VectorIndex):
 
     @functools.cached_property
     def _scan(self):
-        return jax.jit(
-            lambda q, db, alive, k, n: ds.search(q, db, k, self.ctx,
-                                                 metric=self.metric,
-                                                 alive=alive, n=n),
-            static_argnames=("k", "n"))
+        def flat_scan(q, db, alive, k, n):
+            return ds.search(q, db, k, self.ctx, metric=self.metric,
+                             alive=alive, n=n)
+
+        return jax.jit(flat_scan, static_argnames=("k", "n"))
 
     def add(self, vecs: np.ndarray) -> None:
         """Streaming insert: append rows to the scanned corpus. New rows
@@ -550,13 +551,13 @@ class IVFFlatIndex(VectorIndex):
         """Jitted probe scan (static k/nprobe): one XLA call per search
         instead of an eager op-by-op trace — the q=1 serving path is
         dispatch-bound without this."""
-        def fn(q, centroids, lists, list_vecs, list_mask, k, nprobe):
+        def ivf_probe(q, centroids, lists, list_vecs, list_mask, k, nprobe):
             idx = ivf_lib.IVFIndex(centroids=centroids, lists=lists,
                                    list_vecs=list_vecs, list_mask=list_mask,
                                    spill=0)
             return ivf_lib.search(idx, q, k, nprobe=nprobe)
 
-        return jax.jit(fn, static_argnames=("k", "nprobe"))
+        return jax.jit(ivf_probe, static_argnames=("k", "nprobe"))
 
     def set_params(self, params: SearchParams) -> None:
         """Adopt a tuned ``nprobe`` default. ``nprobe`` is fingerprint
@@ -596,11 +597,14 @@ class IVFFlatIndex(VectorIndex):
                                k=k_eff, nprobe=nprobe)
             return _pad_result(v, i, k_req)
 
-        return _timed(run, stats={
-            "distance_evals": _probed_sizes(queries, self._ivf.centroids,
-                                            self._cell_sizes, nprobe),
-            "centroid_evals": float(self._ivf.centroids.shape[0]),
-        })
+        with TraceAnnotation("ivf.count"):
+            evals = _probed_sizes(queries, self._ivf.centroids,
+                                  self._cell_sizes, nprobe)
+        with TraceAnnotation("ivf.probe"):
+            return _timed(run, stats={
+                "distance_evals": evals,
+                "centroid_evals": float(self._ivf.centroids.shape[0]),
+            })
 
     def save(self, directory: str) -> None:
         self._require_built()
@@ -736,9 +740,11 @@ class TwoStageIndex(VectorIndex):
         # the shared stage-2 engine (search.twostage.rerank_candidates):
         # in-jit candidate gather + exact distances, -1 pads from ANY
         # stage-1 tier (IVF probes, batched HNSW beam) pinned to -inf
-        return jax.jit(
-            functools.partial(ts_lib.rerank_candidates, metric=self.metric),
-            static_argnames=("k",))
+        def rerank_candidates(q, db_full, cand, k):
+            return ts_lib.rerank_candidates(q, db_full, cand, k,
+                                            metric=self.metric)
+
+        return jax.jit(rerank_candidates, static_argnames=("k",))
 
     def set_params(self, params: SearchParams) -> None:
         """Adopt a tuned stage-1 budget and forward the rest down the
@@ -753,7 +759,8 @@ class TwoStageIndex(VectorIndex):
                params: Optional[SearchParams] = None) -> SearchResult:
         self._require_built()
         t0 = time.perf_counter()
-        zq = self.reducer.transform(np.asarray(queries, np.float32))
+        with TraceAnnotation("twostage.encode"):
+            zq = self.reducer.transform(np.asarray(queries, np.float32))
         k_eff = min(k, self.ntotal)
         # stage-1 candidate budget: an explicit (tuned / per-call) k1
         # beats the oversample formula; never below k_eff — the rerank
@@ -767,12 +774,15 @@ class TwoStageIndex(VectorIndex):
             k1 = min(k_eff * self.rerank_factor * over, self.ntotal)
         # tombstones are enforced in stage 1: a deleted row never appears
         # even as a pre-rerank candidate, so the rerank can't resurface it
-        stage1 = self.base.search(zq, k1, alive=alive, params=params)
-        cand = jnp.asarray(stage1.indices)
-        q = jnp.asarray(queries, jnp.float32)
-        scores, idx = self._rerank(q, self._db_full, cand, k=k_eff)
-        jax.block_until_ready((scores, idx))
-        dt = time.perf_counter() - t0
+        with TraceAnnotation("twostage.stage1"):
+            stage1 = self.base.search(zq, k1, alive=alive, params=params)
+        with TraceAnnotation("twostage.rerank"):
+            cand = jnp.asarray(stage1.indices)
+            q = jnp.asarray(queries, jnp.float32)
+            scores, idx = self._rerank(q, self._db_full, cand, k=k_eff)
+            jax.block_until_ready((scores, idx))
+            dt = time.perf_counter() - t0
+            scores, idx = np.asarray(scores), np.asarray(idx)
         # total work per query: stage-1 reduced-space evals + the k1
         # full-space rerank distances
         s1_evals = stage1.stats.get("distance_evals", 0.0)
@@ -780,8 +790,7 @@ class TwoStageIndex(VectorIndex):
         stats.update({"distance_evals": s1_evals + float(k1),
                       "stage1_distance_evals": s1_evals,
                       "rerank_evals": float(k1)})
-        return SearchResult(scores=np.asarray(scores),
-                            indices=np.asarray(idx), latency_s=dt,
+        return SearchResult(scores=scores, indices=idx, latency_s=dt,
                             stats=stats)
 
     def save(self, directory: str) -> None:

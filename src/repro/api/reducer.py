@@ -270,8 +270,8 @@ class RAEReducer:
 
         from ..core import rae
 
-        return np.asarray(rae.encode(self.params_,
-                                     jnp.asarray(x, jnp.float32)))
+        return np.asarray(rae.rae_encode(self.params_,
+                                         jnp.asarray(x, jnp.float32)))
 
     def fingerprint(self) -> str:
         """Content hash of the trained encoder (config + weights)."""

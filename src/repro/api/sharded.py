@@ -40,6 +40,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..distributed.partitioning import partition_ivf_cells, partition_rows
 from ..kernels.common import PAD_ID
@@ -234,31 +235,33 @@ class ShardedIndex(VectorIndex):
         al = None if alive is None else np.asarray(alive, bool)
         child_alive = [None if al is None else al[rows]
                        for rows in self._row_maps]
-        if n_sh == 1:
-            results = [self._shards[0].search(
-                q, min(k_req, self._shards[0].ntotal),
-                alive=child_alive[0], params=params)]
-        else:
-            futs = [self._pool.submit(self._shards[s].search, q,
-                                      min(k_req, self._shards[s].ntotal),
-                                      alive=child_alive[s], params=params)
-                    for s in range(n_sh)]
-            results = [f.result() for f in futs]
-        vals = np.concatenate(
-            [np.asarray(r.scores, np.float32) for r in results], axis=1)
-        local = np.concatenate(
-            [np.asarray(r.indices, np.int64) for r in results], axis=1)
-        # local -> global ids shard by shard; -1 pads stay -1
-        gids = np.empty_like(local, dtype=np.int32)
-        off = 0
-        for rows, r in zip(self._row_maps, results):
-            w = r.indices.shape[1]
-            blk = local[:, off:off + w]
-            gids[:, off:off + w] = np.where(
-                blk >= 0, rows[np.clip(blk, 0, len(rows) - 1)], PAD_ID)
-            off += w
-        v, i = topk_merge(jnp.asarray(vals), jnp.asarray(gids), k_req)
-        jax.block_until_ready((v, i))
+        with TraceAnnotation("sharded.scan"):
+            if n_sh == 1:
+                results = [self._shards[0].search(
+                    q, min(k_req, self._shards[0].ntotal),
+                    alive=child_alive[0], params=params)]
+            else:
+                futs = [self._pool.submit(self._shards[s].search, q,
+                                          min(k_req, self._shards[s].ntotal),
+                                          alive=child_alive[s], params=params)
+                        for s in range(n_sh)]
+                results = [f.result() for f in futs]
+        with TraceAnnotation("sharded.merge"):
+            vals = np.concatenate(
+                [np.asarray(r.scores, np.float32) for r in results], axis=1)
+            local = np.concatenate(
+                [np.asarray(r.indices, np.int64) for r in results], axis=1)
+            # local -> global ids shard by shard; -1 pads stay -1
+            gids = np.empty_like(local, dtype=np.int32)
+            off = 0
+            for rows, r in zip(self._row_maps, results):
+                w = r.indices.shape[1]
+                blk = local[:, off:off + w]
+                gids[:, off:off + w] = np.where(
+                    blk >= 0, rows[np.clip(blk, 0, len(rows) - 1)], PAD_ID)
+                off += w
+            v, i = topk_merge(jnp.asarray(vals), jnp.asarray(gids), k_req)
+            jax.block_until_ready((v, i))
         dt = time.perf_counter() - t0
         scores = np.array(v)  # copy: jax buffers are read-only views
         idx = np.asarray(i)

@@ -56,6 +56,13 @@ def encode(params: Params, x: jax.Array) -> jax.Array:
     return y
 
 
+@jax.jit
+def rae_encode(params: Params, x: jax.Array) -> jax.Array:
+    """:func:`encode` as one device program with a stable name, the query
+    encode of the serving path (``RAEReducer.transform``)."""
+    return encode(params, x)
+
+
 def decode(params: Params, z: jax.Array) -> jax.Array:
     y = jnp.matmul(z, params["w_d"], precision=EXACT)
     if "b_d" in params:

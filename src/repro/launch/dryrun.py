@@ -9,8 +9,7 @@ decode_step / serve / retrieval+top-k) with production shardings on the
 records:
   * memory_analysis()  — per-device argument/output/temp bytes (fits check),
   * cost_analysis()    — per-device HLO FLOPs/bytes (scan bodies counted
-                         once; see benchmarks/roofline.py for the adjusted
-                         analytic terms),
+                         once),
   * loop-adjusted collective traffic from the compiled HLO
     (launch/hlo_analysis.py),
   * sharding fallbacks (logical axes that degraded to replication).

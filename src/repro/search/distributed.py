@@ -187,29 +187,31 @@ def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
     out_spec = ctx.pspec((queries.shape[0], k))
 
     def f(q_l, db_l, *alive_l):
-        s = sharded_scores(q_l, db_l, metric, MeshCtx(mesh=None))
-        shard = _linear_shard_index(mesh, axes)
-        # pin pad rows BEFORE the local top-k: a padded (zero) row must
-        # not displace a real candidate inside the shard
-        grow = shard * n_loc + jnp.arange(s.shape[1], dtype=jnp.int32)
-        keep = grow[None, :] < n
-        if alive_l:  # tombstones ride the same never-wins lane as pads
-            keep = keep & alive_l[0][None, :]
-        s = jnp.where(keep, s, NEG_INF)
-        v, i = jax.lax.top_k(s, kl)             # [Q, kl] local
-        gi = shard * n_loc + i
-        dead = (gi >= n) | (v <= NEG_INF / 2)
-        v = jnp.where(dead, NEG_INF, v)
-        gi = jnp.where(dead, PAD_ID, gi)
-        if kl < k:
-            pad = k - kl
-            v = jnp.concatenate(
-                [v, jnp.full((v.shape[0], pad), NEG_INF, v.dtype)], 1)
-            gi = jnp.concatenate(
-                [gi, jnp.full((gi.shape[0], pad), PAD_ID, gi.dtype)], 1)
-        vs = jax.lax.all_gather(v, axes, axis=1, tiled=True)   # [Q, k*S]
-        gis = jax.lax.all_gather(gi, axes, axis=1, tiled=True)
-        return topk_merge(vs, gis, k)
+        with jax.named_scope("shard_scan"):
+            s = sharded_scores(q_l, db_l, metric, MeshCtx(mesh=None))
+            shard = _linear_shard_index(mesh, axes)
+            # pin pad rows BEFORE the local top-k: a padded (zero) row must
+            # not displace a real candidate inside the shard
+            grow = shard * n_loc + jnp.arange(s.shape[1], dtype=jnp.int32)
+            keep = grow[None, :] < n
+            if alive_l:  # tombstones ride the same never-wins lane as pads
+                keep = keep & alive_l[0][None, :]
+            s = jnp.where(keep, s, NEG_INF)
+            v, i = jax.lax.top_k(s, kl)             # [Q, kl] local
+            gi = shard * n_loc + i
+            dead = (gi >= n) | (v <= NEG_INF / 2)
+            v = jnp.where(dead, NEG_INF, v)
+            gi = jnp.where(dead, PAD_ID, gi)
+            if kl < k:
+                pad = k - kl
+                v = jnp.concatenate(
+                    [v, jnp.full((v.shape[0], pad), NEG_INF, v.dtype)], 1)
+                gi = jnp.concatenate(
+                    [gi, jnp.full((gi.shape[0], pad), PAD_ID, gi.dtype)], 1)
+        with jax.named_scope("topk_merge"):
+            vs = jax.lax.all_gather(v, axes, axis=1, tiled=True)  # [Q, k*S]
+            gis = jax.lax.all_gather(gi, axes, axis=1, tiled=True)
+            return topk_merge(vs, gis, k)
 
     in_specs = (q_spec, db_spec)
     args = (queries, db)

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -60,6 +61,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..api.index import SearchParams, SearchResult, VectorIndex
 from ..tune.autotune import OperatingCurve
@@ -137,6 +139,7 @@ class SearchEngine:
         self._start_lock = threading.Lock()
         self._mutations = 0       # mutate() calls applied
         self._swaps = 0           # set_index()/hot_swap() promotions
+        self._batch_ids = itertools.count(1)  # engine.batch span metadata
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -368,10 +371,12 @@ class SearchEngine:
         whole batch; per-row attribution happens in ``_run_batch``."""
         esc = self._escalation
         if esc is None:
-            r = self.index.search(qs, k, params=self._params)
+            with TraceAnnotation("index.search"):
+                r = self.index.search(qs, k, params=self._params)
             return r, np.zeros(qs.shape[0], bool)
         kk = k + esc.delta
-        r1 = self.index.search(qs, kk, params=self._params)
+        with TraceAnnotation("index.search"):
+            r1 = self.index.search(qs, kk, params=self._params)
         if r1.scores.shape[1] < kk:
             # corpus smaller than k + delta: a wider search has nothing
             # more to find, and the margin is undefined — serve pass 1,
@@ -394,7 +399,8 @@ class SearchEngine:
             if bucket > n_esc:
                 sub = np.concatenate(
                     [sub, np.repeat(sub[:1], bucket - n_esc, axis=0)])
-            r2 = self.index.search(sub, kk, params=self._esc_params)
+            with TraceAnnotation("index.search"):
+                r2 = self.index.search(sub, kk, params=self._esc_params)
             scores[mask] = np.asarray(r2.scores)[:n_esc, :k]
             idx[mask] = np.asarray(r2.indices)[:n_esc, :k]
             e2 = r2.stats.get("distance_evals", 0.0)
@@ -587,45 +593,54 @@ class SearchEngine:
 
     def _run_batch(self, k: int, reqs: list[_Request]) -> list[SearchResult]:
         """Executor-side: pad to the bucket, search once (escalating
-        unstable rows at the operating point), slice per caller."""
+        unstable rows at the operating point), slice per caller. The
+        ``engine.batch`` span carries the batch's id, size and bucket; the
+        index and scatter spans nest inside it on this thread."""
+        entered = time.perf_counter()
         size = len(reqs)
         bucket = next(b for b in self.buckets if b >= size)
-        qs = np.stack([r.q for r in reqs])
-        if bucket > size:
-            # pad with a REAL query row (not zeros): identical numerics to
-            # the unpadded rows, and never a degenerate all-zero distance
-            qs = np.concatenate(
-                [qs, np.repeat(qs[:1], bucket - size, axis=0)])
-        res, esc_mask = self._escalated_search(qs, k)
-        done = time.perf_counter()
-        e1 = res.stats.get("pass1_distance_evals",
-                           res.stats.get("distance_evals", 0.0))
-        e2 = res.stats.get("pass2_distance_evals", 0.0)
-        out = []
-        for i, req in enumerate(reqs):
-            stats = dict(res.stats)
-            if self._escalation is not None:
-                # per-row attribution: an escalated row paid both passes,
-                # a stable row only the first
-                stats["distance_evals"] = e1 + (e2 if esc_mask[i] else 0.0)
-                stats["escalated"] = bool(esc_mask[i])
-            single = SearchResult(scores=res.scores[i:i + 1].copy(),
-                                  indices=res.indices[i:i + 1].copy(),
-                                  latency_s=res.latency_s,
-                                  stats=stats)
-            if self.cache.maxsize:
-                # the cached object IS the returned object: freeze its
-                # arrays so a caller mutating its result can't poison
-                # every future hit on this key
-                single.scores.setflags(write=False)
-                single.indices.setflags(write=False)
-                self.cache.put(self._cache_key(req.q, k), single)
-            out.append(single)
-        self.metrics.record_batch(
-            size=size, bucket=bucket,
-            latencies_s=[done - r.t_enq for r in reqs],
-            distance_evals=res.distance_evals,
-            escalated=int(esc_mask[:size].sum()))
+        with TraceAnnotation("engine.batch", batch=next(self._batch_ids),
+                             size=size, bucket=bucket):
+            qs = np.stack([r.q for r in reqs])
+            if bucket > size:
+                # pad with a REAL query row (not zeros): identical numerics
+                # to the unpadded rows, and never a degenerate all-zero
+                # distance
+                qs = np.concatenate(
+                    [qs, np.repeat(qs[:1], bucket - size, axis=0)])
+            res, esc_mask = self._escalated_search(qs, k)
+            done = time.perf_counter()
+            with TraceAnnotation("engine.scatter"):
+                e1 = res.stats.get("pass1_distance_evals",
+                                   res.stats.get("distance_evals", 0.0))
+                e2 = res.stats.get("pass2_distance_evals", 0.0)
+                out = []
+                for i, req in enumerate(reqs):
+                    stats = dict(res.stats)
+                    if self._escalation is not None:
+                        # per-row attribution: an escalated row paid both
+                        # passes, a stable row only the first
+                        stats["distance_evals"] = e1 + (
+                            e2 if esc_mask[i] else 0.0)
+                        stats["escalated"] = bool(esc_mask[i])
+                    single = SearchResult(
+                        scores=res.scores[i:i + 1].copy(),
+                        indices=res.indices[i:i + 1].copy(),
+                        latency_s=res.latency_s, stats=stats)
+                    if self.cache.maxsize:
+                        # the cached object IS the returned object: freeze
+                        # its arrays so a caller mutating its result can't
+                        # poison every future hit on this key
+                        single.scores.setflags(write=False)
+                        single.indices.setflags(write=False)
+                        self.cache.put(self._cache_key(req.q, k), single)
+                    out.append(single)
+                self.metrics.record_batch(
+                    size=size, bucket=bucket,
+                    latencies_s=[done - r.t_enq for r in reqs],
+                    distance_evals=res.distance_evals,
+                    escalated=int(esc_mask[:size].sum()),
+                    wait_s=sum(entered - r.t_enq for r in reqs))
         return out
 
     # ------------------------------------------------------------------

@@ -5,7 +5,17 @@ thread and powers both ``engine.stats()`` and the HTTP ``/stats`` page.
 Latency percentiles come from a bounded window (the most recent
 ``window`` requests) so a long-lived server reports current behavior, not
 its lifetime average; QPS is reported both lifetime and over the same
-window.
+window. ``wait_s_total`` adds up every request's time from its enqueue to
+the moment the executor took its batch (queueing and coalescing), raw for
+a reader taking deltas and as ``wait_ms_mean`` for ``/stats``.
+
+Where the time of a batch goes is traced, not counted: the engine and the
+index stack open ``jax.profiler.TraceAnnotation`` spans at each layer's
+boundary (``engine.batch`` with the batch's id, size and bucket,
+``index.search``, ``engine.scatter``, ``twostage.encode`` / ``stage1`` /
+``rerank``, ``ivf.probe`` / ``count``, ``sharded.scan`` / ``merge``). With
+no profiler attached each is a check and a return; under
+``jax.profiler.trace`` they land on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -31,10 +41,11 @@ class EngineMetrics:
         self._evals_sum = 0.0        # distance_evals weighted by requests
         self._evals_n = 0
         self.n_escalated = 0         # rows re-run at the next ladder rung
+        self.wait_s = 0.0            # enqueue -> batch taken, summed
 
     def record_batch(self, size: int, bucket: int, latencies_s: list,
                      distance_evals: Optional[float],
-                     escalated: int = 0) -> None:
+                     escalated: int = 0, wait_s: float = 0.0) -> None:
         now = time.perf_counter()
         with self._lock:
             self.n_batches += 1
@@ -47,6 +58,7 @@ class EngineMetrics:
                 self._evals_sum += distance_evals * size
                 self._evals_n += size
             self.n_escalated += escalated
+            self.wait_s += wait_s
 
     def record_cached(self, latency_s: float) -> None:
         now = time.perf_counter()
@@ -74,6 +86,9 @@ class EngineMetrics:
                                     sorted(self.batch_hist.items())},
                 "bucket_hist": {str(b): c for b, c in
                                 sorted(self.bucket_hist.items())},
+                "wait_s_total": self.wait_s,
+                "wait_ms_mean": round(self.wait_s / self.n_requests * 1e3, 3)
+                if self.n_requests else 0.0,
             }
             if lat.size:
                 out["latency_ms"] = {
