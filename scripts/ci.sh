@@ -71,7 +71,7 @@ done
 # shared harness parametrizes test ids by kernel name) — dropping one
 # silently un-gates that kernel's pad/edge paths.
 REQUIRED_KERNELS=(l2_topk rae_encode flash_decode embedding_bag pq_adc
-                  graph_beam graph_beam_q topk_merge)
+                  graph_beam graph_beam_q topk_merge ivf_scan)
 for kern in "${REQUIRED_KERNELS[@]}"; do
     if ! grep -q "${kern}" <<<"$collect_out"; then
         echo "FATAL: kernel-parity cases for ${kern} not collected" >&2

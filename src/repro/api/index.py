@@ -145,7 +145,9 @@ class SearchResult:
     ``distance_evals`` — the mean number of corpus vectors whose distance
     to the query was evaluated (flat scan = N, IVF = probed list sizes,
     HNSW = beam-visited count) — the sublinearity axis benchmarks report
-    next to recall and QPS."""
+    next to recall and QPS. ``IVFFlatIndex`` adds ``probe_bytes``, the
+    mean bytes of the store the TPU probe scan (``ivf_scan``) reads per
+    query: its probed cells' members rounded up to whole blocks."""
 
     scores: np.ndarray
     indices: np.ndarray
@@ -318,19 +320,25 @@ def _timed(fn: Callable[[], tuple[jax.Array, jax.Array]],
                         latency_s=dt, stats=dict(stats or {}))
 
 
-def _probed_sizes(queries: np.ndarray, centroids: np.ndarray,
-                  cell_sizes: np.ndarray, nprobe: int) -> float:
-    """Mean members the probe scan evaluates per query — the IVF
-    ``distance_evals`` stat. Recomputes the nprobe-nearest cells on host
-    (Q x C, negligible next to the scan itself) so the jitted search path
-    stays untouched; the centroid scan is reported separately by callers
-    as ``centroid_evals``."""
+def _probed_cells(queries: np.ndarray, centroids: np.ndarray,
+                  nprobe: int) -> np.ndarray:
+    """The nprobe-nearest cells [Q, nprobe] of each query, recomputed on
+    host (Q x C, negligible next to the scan itself) so the jitted search
+    path stays untouched; feeds the probe's host-side stats."""
     q = np.asarray(queries, np.float32)
     c = np.asarray(centroids, np.float32)
     d2 = (np.sum(q * q, 1)[:, None] - 2.0 * q @ c.T
           + np.sum(c * c, 1)[None, :])
     p = min(nprobe, c.shape[0])
-    cells = np.argpartition(d2, p - 1, axis=1)[:, :p]
+    return np.argpartition(d2, p - 1, axis=1)[:, :p]
+
+
+def _probed_sizes(queries: np.ndarray, centroids: np.ndarray,
+                  cell_sizes: np.ndarray, nprobe: int) -> float:
+    """Mean members the probe scan evaluates per query — the IVF
+    ``distance_evals`` stat; the centroid scan is reported separately by
+    callers as ``centroid_evals``."""
+    cells = _probed_cells(queries, centroids, nprobe)
     return float(cell_sizes[cells].sum(axis=1).mean())
 
 
@@ -469,8 +477,7 @@ class IVFFlatIndex(VectorIndex):
     @property
     def bytes_per_vector(self) -> float:
         """f32 list vector + int32 row id."""
-        self._require_built()
-        return float(self._ivf.list_vecs.shape[2] * 4 + 4)
+        return float(self.dim * 4 + 4)
 
     @property
     def dim(self) -> int:
@@ -478,10 +485,12 @@ class IVFFlatIndex(VectorIndex):
         return int(self._ivf.centroids.shape[1])
 
     def _fingerprint_state(self) -> list:
-        # list_vecs is what search actually scores against — centroids +
-        # id lists alone could collide across corpora with equal means
+        # the member rows are what search actually scores against —
+        # centroids + id lists alone could collide across corpora with
+        # equal means; hashed as rows [C, cap, d] (the saved form), so the
+        # device store's layout is not part of the index's identity
         return [f"nprobe={self.nprobe}", self._ivf.centroids,
-                self._ivf.lists, self._ivf.list_vecs]
+                self._ivf.lists, ivf_lib.store_rows(self._ivf)]
 
     def build(self, corpus: np.ndarray) -> "IVFFlatIndex":
         corpus = jnp.asarray(corpus, jnp.float32)
@@ -508,7 +517,7 @@ class IVFFlatIndex(VectorIndex):
         cells = np.argmin(d2, axis=1)
         lists = np.asarray(self._ivf.lists).copy()
         mask = np.asarray(self._ivf.list_mask).copy()
-        lvecs = np.asarray(self._ivf.list_vecs).copy()
+        lvecs = ivf_lib.store_rows(self._ivf).copy()
         need = mask.sum(axis=1)
         np.add.at(need, cells, 1)
         cap = lists.shape[1]
@@ -530,11 +539,13 @@ class IVFFlatIndex(VectorIndex):
             lvecs[c, : len(ids)] = vv
             lists[c, : len(ids)] = ids
             mask[c, : len(ids)] = True
+        self._cell_sizes = mask.sum(axis=1)
         self._ivf = ivf_lib.IVFIndex(
             centroids=self._ivf.centroids, lists=jnp.asarray(lists),
-            list_vecs=jnp.asarray(lvecs), list_mask=jnp.asarray(mask),
+            list_vecs=ivf_lib.pack_store(lvecs),
+            list_mask=jnp.asarray(mask),
+            extent=jnp.asarray(self._cell_sizes, jnp.int32),
             spill=self._ivf.spill)
-        self._cell_sizes = mask.sum(axis=1)
         self._ntotal += int(nv.shape[0])
 
     def cell_imbalance(self) -> float:
@@ -551,10 +562,11 @@ class IVFFlatIndex(VectorIndex):
         """Jitted probe scan (static k/nprobe): one XLA call per search
         instead of an eager op-by-op trace — the q=1 serving path is
         dispatch-bound without this."""
-        def ivf_probe(q, centroids, lists, list_vecs, list_mask, k, nprobe):
+        def ivf_probe(q, centroids, lists, list_vecs, list_mask, extent, k,
+                      nprobe):
             idx = ivf_lib.IVFIndex(centroids=centroids, lists=lists,
                                    list_vecs=list_vecs, list_mask=list_mask,
-                                   spill=0)
+                                   extent=extent, spill=0)
             return ivf_lib.search(idx, q, k, nprobe=nprobe)
 
         return jax.jit(ivf_probe, static_argnames=("k", "nprobe"))
@@ -592,19 +604,24 @@ class IVFFlatIndex(VectorIndex):
             lists = jnp.where(mask, lists, -1)
 
         def run():
+            # the extent is the build's prefix length, never counted from
+            # ``mask``: tombstones leave holes with live rows behind them
             v, i = self._probe(q, self._ivf.centroids, lists,
-                               self._ivf.list_vecs, mask,
+                               self._ivf.list_vecs, mask, self._ivf.extent,
                                k=k_eff, nprobe=nprobe)
             return _pad_result(v, i, k_req)
 
         with TraceAnnotation("ivf.count"):
-            evals = _probed_sizes(queries, self._ivf.centroids,
-                                  self._cell_sizes, nprobe)
-        with TraceAnnotation("ivf.probe"):
-            return _timed(run, stats={
-                "distance_evals": evals,
+            cells = _probed_cells(queries, self._ivf.centroids, nprobe)
+            sizes = self._cell_sizes[cells]
+            stats = {
+                "distance_evals": float(sizes.sum(axis=1).mean()),
                 "centroid_evals": float(self._ivf.centroids.shape[0]),
-            })
+                "probe_bytes": float(ivf_lib.probe_bytes(self._ivf, sizes)
+                                     .sum(axis=1).mean()),
+            }
+        with TraceAnnotation("ivf.probe"):
+            return _timed(run, stats=stats)
 
     def save(self, directory: str) -> None:
         self._require_built()
@@ -615,7 +632,7 @@ class IVFFlatIndex(VectorIndex):
         _save_dir(directory, meta, {
             "centroids": np.asarray(self._ivf.centroids),
             "lists": np.asarray(self._ivf.lists),
-            "list_vecs": np.asarray(self._ivf.list_vecs),
+            "list_vecs": ivf_lib.store_rows(self._ivf),
             "list_mask": np.asarray(self._ivf.list_mask),
         })
 
@@ -627,8 +644,9 @@ class IVFFlatIndex(VectorIndex):
         self._ivf = ivf_lib.IVFIndex(
             centroids=jnp.asarray(a["centroids"]),
             lists=jnp.asarray(a["lists"]),
-            list_vecs=jnp.asarray(a["list_vecs"]),
+            list_vecs=ivf_lib.pack_store(a["list_vecs"]),
             list_mask=jnp.asarray(a["list_mask"]),
+            extent=jnp.asarray(a["list_mask"].sum(axis=1), jnp.int32),
             spill=int(meta.get("spill", 0)))
         self._cell_sizes = a["list_mask"].sum(axis=1)
         self._ntotal = int(meta["ntotal"])

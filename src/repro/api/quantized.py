@@ -413,8 +413,9 @@ class IVFSQ8Index(_IVFQuantBase):
         corpus = jnp.asarray(corpus, jnp.float32)
         coarse = self._build_coarse(corpus)
         self._sq = qz.sq8_train(corpus)
-        c, cap, d = coarse.list_vecs.shape
-        flat = qz.sq8_encode(self._sq, coarse.list_vecs.reshape(c * cap, d))
+        rows = ivf_lib.store_rows(coarse)
+        c, cap, d = rows.shape
+        flat = qz.sq8_encode(self._sq, jnp.asarray(rows.reshape(c * cap, d)))
         self._codes = flat.reshape(c, cap, d)
         self._recon_sq = qz.sq8_recon_sq_norms(
             self._sq, flat).reshape(c, cap)
@@ -503,8 +504,9 @@ class IVFPQIndex(_IVFQuantBase):
         coarse = self._build_coarse(corpus)
         self._pq = qz.pq_train(corpus, self.m, self.bits,
                                iters=self.pq_iters, seed=self.seed)
-        c, cap, d = coarse.list_vecs.shape
-        flat = qz.pq_encode(self._pq, coarse.list_vecs.reshape(c * cap, d))
+        rows = ivf_lib.store_rows(coarse)
+        c, cap, d = rows.shape
+        flat = qz.pq_encode(self._pq, jnp.asarray(rows.reshape(c * cap, d)))
         self._codes = flat.reshape(c, cap, self.m)
         return self
 

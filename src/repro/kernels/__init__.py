@@ -8,6 +8,7 @@ rae_encode    — RAE encoder GEMM + fused L2-normalize epilogue
 flash_decode  — split-KV online-softmax decode attention
 embedding_bag — scalar-prefetch gather-reduce (torch EmbeddingBag on TPU)
 pq_adc        — fused PQ ADC scan: LUT build + one-hot code gather + top-k
+ivf_scan      — IVF probe: scalar-prefetch gather + L2 of the probed cells' rows
 graph_beam    — fused neighbor gather + L2 + beam merge (one batched HNSW hop)
 graph_beam_q  — the quantized hop: SQ8/PQ code gather + asymmetric score + merge
 topk_merge    — deterministic scatter-gather top-k merge (sharded search)
@@ -17,6 +18,7 @@ from .embedding_bag.ops import embedding_bag
 from .flash_decode.ops import flash_decode
 from .graph_beam.ops import graph_beam
 from .graph_beam_q.ops import graph_beam_q
+from .ivf_scan.ops import ivf_scan
 from .l2_topk.ops import l2_topk
 from .pq_adc.ops import pq_adc
 from .rae_encode.ops import rae_encode
@@ -24,4 +26,4 @@ from .topk_merge.ops import topk_merge
 
 __all__ = ["NEG_INF", "PAD_ID", "PAD_PENALTY", "canonicalize_pads",
            "embedding_bag", "flash_decode", "graph_beam", "graph_beam_q",
-           "l2_topk", "pq_adc", "rae_encode", "topk_merge"]
+           "ivf_scan", "l2_topk", "pq_adc", "rae_encode", "topk_merge"]

@@ -177,6 +177,47 @@ def test_twostage_ivf_padding_never_outranks_real(queries):
     assert not np.any(valid[:, 1:] & ~valid[:, :-1])
 
 
+def test_ivf_probe_bytes_and_answers(small_corpus, queries):
+    """``probe_bytes`` is the whole member blocks of the probed cells up
+    to each extent, clipped at the store's width; the answers are the
+    exact top-k of the probed cells' members."""
+    from repro.kernels.ivf_scan.kernel import block_cols
+
+    idx = api.IVFFlatIndex(n_cells=16, nprobe=4).build(small_corpus)
+    res = idx.search(queries, 10)
+    ivf = idx._ivf
+    cent = np.asarray(ivf.centroids)
+    d2c = ((queries[:, None, :] - cent[None]) ** 2).sum(-1)
+    cells = np.argsort(d2c, axis=1)[:, :4]
+    _, depth, width = ivf.list_vecs.shape
+    block = block_cols(width, depth)
+    sizes = np.asarray(ivf.extent)[cells]
+    read = np.minimum(np.maximum(-(-sizes // block), 1) * block, width)
+    assert res.stats["probe_bytes"] == pytest.approx(
+        (read * depth * 4).sum(axis=1).mean())
+    assert res.distance_evals == pytest.approx(sizes.sum(axis=1).mean())
+    lists = np.asarray(ivf.lists)
+    for qi, q in enumerate(queries):
+        members = lists[cells[qi]].ravel()
+        members = members[members >= 0]
+        dist = ((small_corpus[members] - q) ** 2).sum(-1)
+        order = np.argsort(dist, kind="stable")[:10]
+        np.testing.assert_array_equal(res.indices[qi], members[order])
+        np.testing.assert_allclose(-res.scores[qi], dist[order], rtol=1e-4,
+                                   atol=1e-4)
+
+
+def test_ivf_fingerprint_hashes_member_rows(small_corpus):
+    """The IVF fingerprint hashes the member rows [C, cap, d] (the saved
+    form), not the device store, whose layout may pad and transpose."""
+    idx = api.IVFFlatIndex(n_cells=16, nprobe=4).build(small_corpus)
+    rows = idx._fingerprint_state()[-1]
+    lists = np.asarray(idx._ivf.lists)
+    assert rows.shape == lists.shape + (small_corpus.shape[1],)
+    live = lists >= 0
+    np.testing.assert_array_equal(rows[live], small_corpus[lists[live]])
+
+
 def test_twostage_fits_reducer_without_fitted_attr(small_corpus, queries):
     """A minimal third-party Reducer (no `fitted` attribute) must be fitted
     by build, not silently skipped."""

@@ -13,13 +13,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.kernels import (embedding_bag, flash_decode, graph_beam,
-                           graph_beam_q, l2_topk, pq_adc, rae_encode,
-                           topk_merge)
+                           graph_beam_q, ivf_scan, l2_topk, pq_adc,
+                           rae_encode, topk_merge)
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 from repro.kernels.flash_decode.ref import flash_decode_ref
 from repro.kernels.graph_beam.ref import NEG_INF, graph_beam_ref
 from repro.kernels.graph_beam_q.ref import graph_beam_q_ref
+from repro.kernels.ivf_scan.kernel import block_cols, store_shape
+from repro.kernels.ivf_scan.ref import ivf_scan_ref
 from repro.kernels.l2_topk.ref import l2_topk_ref
 from repro.kernels.pq_adc.ref import pq_adc_ref
 from repro.kernels.rae_encode.ref import rae_encode_ref
@@ -459,6 +462,67 @@ def _parity_topk_merge(case, dtype):
     assert np.all(v[i >= 0] > NEG_INF)  # live slots never carry pad scores
 
 
+def _parity_ivf_scan(case, dtype):
+    q_n, n_probe, c, cap, d = case
+    rng = np.random.default_rng(q_n + c + cap + d)
+    shape = store_shape(c, cap, d)
+    lv = _arr(c + cap, shape, dtype).at[:, d:].set(0)  # pad features are 0
+    qs = _arr(q_n, (q_n, d), dtype)
+    block = block_cols(shape[2], shape[1])
+    ext = rng.integers(0, cap + 1, c)
+    # an empty cell, a full one (its last block partial where the width is
+    # not a block multiple) and an extent inside a block, all probed by
+    # query 0
+    ext[:3] = 0, cap, min(block + 3, cap - 1)
+    ext = jnp.asarray(ext, jnp.int32)
+    cells = np.stack([rng.permutation(c)[:n_probe] for _ in range(q_n)])
+    cells[0, :3] = 0, 1, 2
+    cells = jnp.asarray(cells, jnp.int32)
+    got = np.asarray(ivf_scan(qs, cells, ext, lv, impl="pallas",
+                              interpret=True))
+    want = np.asarray(ivf_scan_ref(qs, cells, ext, lv))
+    assert got.shape == want.shape == (q_n, n_probe, shape[2])
+    dead = (np.arange(shape[2])
+            >= np.asarray(ext)[np.asarray(cells)][..., None])
+    assert np.array_equal(np.isneginf(got), dead)
+    assert np.array_equal(np.isneginf(want), dead)
+    np.testing.assert_allclose(got[~dead], want[~dead], rtol=2e-5,
+                               atol=2e-4)
+
+
+def _parity_ivf_scan_alive(case, dtype):
+    """A tombstone inside a cell's prefix folds into the list mask only:
+    the scan still reads the cell to its extent, so the live rows behind
+    the tombstone surface, on the Pallas path as on the ref."""
+    n, d, n_cells = case
+    rng = np.random.default_rng(n + d)
+    corpus = rng.standard_normal((n, d)).astype(np.float32)
+    probe = api.IVFFlatIndex(n_cells=n_cells, nprobe=2).build(corpus)
+    ivf = probe._ivf
+    cell = int(np.argmax(np.asarray(ivf.extent)))
+    members = np.asarray(ivf.lists)[cell, :int(ivf.extent[cell])]
+    first, behind = int(members[0]), int(members[-1])
+    alive = np.ones(n, bool)
+    alive[first] = False
+    q = np.asarray(jnp.asarray(corpus[[behind, first]], dtype), np.float32)
+    ref = probe.search(q, 5, alive=alive)
+    traced = []
+
+    def pallas_scan(*args):
+        traced.append(True)
+        return ivf_scan(*args, impl="pallas", interpret=True)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr("repro.search.ivf.ivf_scan", pallas_scan)
+        mp.delattr(probe, "_probe")  # the cached jit traced the ref path
+        got = probe.search(q, 5, alive=alive)
+    assert traced
+    assert got.indices[0, 0] == behind
+    assert first not in got.indices
+    np.testing.assert_array_equal(got.indices, ref.indices)
+    np.testing.assert_allclose(got.scores, ref.scores, rtol=1e-5, atol=1e-4)
+
+
 # case ids name the edge they exercise; every kernel gets n-not-divisible-
 # by-block, a k/cur overflow variant where meaningful, and d=1.
 PARITY_CASES = [
@@ -505,6 +569,16 @@ PARITY_CASES = [
     ("topk_merge", "ragged_q", (19, 96, 8, 16), _parity_topk_merge),
     ("topk_merge", "k_gt_c", (4, 6, 10, 8), _parity_topk_merge),
     ("topk_merge", "c1", (5, 1, 3, 8), _parity_topk_merge),
+    # (q_n, nprobe, C, cap, d): a store width that is a ragged multiple of
+    # the block at d 384 (640-member blocks) and d 64 (4096), one block
+    # for the whole width, every cell probed, d not a whole sublane; each
+    # case holds an empty cell, a full one and an extent inside a block
+    ("ivf_scan", "d384_q16_ragged_width", (16, 3, 6, 1500, 384),
+     _parity_ivf_scan),
+    ("ivf_scan", "d64_q1_nprobe_all", (1, 5, 5, 4500, 64), _parity_ivf_scan),
+    ("ivf_scan", "one_block", (4, 3, 7, 100, 13), _parity_ivf_scan),
+    # (n, d, n_cells): the alive path through IVFFlatIndex.search
+    ("ivf_scan", "alive_tombstone", (600, 16, 8), _parity_ivf_scan_alive),
 ]
 
 

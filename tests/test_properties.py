@@ -112,8 +112,9 @@ def _recalls_vs_nprobe(seed, quant):
     from repro.core.metrics import knn_indices, set_overlap
 
     pq = qz.pq_train(x, m=4, bits=8, iters=8, seed=seed)
-    c, cap, d = index.list_vecs.shape
-    codes = qz.pq_encode(pq, index.list_vecs.reshape(c * cap, d)) \
+    rows = ivf_lib.store_rows(index)
+    c, cap, d = rows.shape
+    codes = qz.pq_encode(pq, jnp.asarray(rows.reshape(c * cap, d))) \
         .reshape(c, cap, 4)
     exact = knn_indices(q, x, 10)
     out = []

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.graph_beam.kernel import gather_layout, graph_beam_pallas
 from repro.kernels.graph_beam_q.kernel import graph_beam_q_pallas
+from repro.kernels.ivf_scan.kernel import ivf_scan_pallas, store_shape
 from repro.kernels.l2_topk.kernel import l2_topk_pallas
 from repro.kernels.pq_adc.kernel import pq_adc_pallas
 from repro.kernels.topk_merge.kernel import topk_merge_pallas
@@ -78,6 +79,27 @@ def test_pq_adc_compiles(one_chip):
         ((128, m * dsub), F32), ((m * ksub, dsub), F32),
         ((20_480, m), I32), ((20_480,), F32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("q_n,n_probe,n_cells,cap,d", [
+    (16, 64, 1024, 2442, 384),   # RAE384,IVF1024 over 1M rows, nprobe 64
+    (16, 16, 256, 9766, 64),     # RAE64,IVF256 over 1M rows, nprobe 16
+    # the autotuner's top nprobe rung, min(C, 512), at both shapes
+    (16, 512, 1024, 2442, 384),
+    (16, 256, 256, 9766, 64),
+])
+def test_ivf_scan_compiles(one_chip, q_n, n_probe, n_cells, cap, d):
+    """The probe scan at the served shapes, on the store in its default
+    layout: the kernel reads it in place, so the program holds no copy of
+    it (a copy would be the store's size in temporaries), and its VMEM
+    does not grow with nprobe (the top rung would not fit otherwise)."""
+    shape = store_shape(n_cells, cap, d)
+    compiled = jax.jit(ivf_scan_pallas).lower(*(
+        jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+        for s, t in (((shape[1], q_n), F32), ((q_n, n_probe), I32),
+                     ((n_cells,), I32), (shape, F32)))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < n_cells * cap * d
 
 
 # graph hop shapes: Q 32 queries, N 20,000 nodes, d 64, W = ef = 64

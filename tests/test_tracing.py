@@ -151,7 +151,7 @@ def test_hot_path_programs_carry_stable_names(two_stage, corpus, queries):
         "rae_encode": rae.rae_encode.lower(two_stage.reducer.params_, q),
         "ivf_probe": two_stage.base._probe.lower(
             zq, ivf.centroids, ivf.lists, ivf.list_vecs, ivf.list_mask,
-            k=K, nprobe=8),
+            ivf.extent, k=K, nprobe=8),
         "rerank_candidates": two_stage._rerank.lower(
             q, two_stage._db_full, cand, k=K),
         "flat_scan": flat._scan.lower(q, flat._db, None, k=K, n=N),
