@@ -34,6 +34,7 @@ from ..models.common import NULL_CTX, MeshCtx
 from ..search import distributed as ds
 from ..search import ivf as ivf_lib
 from ..search import twostage as ts_lib
+from ..search.twostage import DevicePart
 from .reducer import Reducer, load_reducer
 
 _META = "meta.json"
@@ -279,6 +280,17 @@ class VectorIndex:
         identically to the pre-params path."""
         raise NotImplementedError
 
+    def device_search(self, k: int, alive: Optional[np.ndarray] = None,
+                      params: Optional[SearchParams] = None
+                      ) -> Optional[DevicePart]:
+        """This tier's ``search(queries, k, alive, params)`` as a
+        :class:`~repro.search.twostage.DevicePart`, for ``TwoStageIndex``
+        to run inside its one program; None where the tier has none (a
+        host traversal, codes, children), and the composite then calls
+        ``search``. Same answers and stats as ``search``."""
+        del k, alive, params
+        return None
+
     def set_params(self, params: SearchParams) -> None:
         """Apply ``params``'s set knobs as this index's new DEFAULTS
         (tuned operating point). Knob attributes are fingerprint state on
@@ -320,26 +332,51 @@ def _timed(fn: Callable[[], tuple[jax.Array, jax.Array]],
                         latency_s=dt, stats=dict(stats or {}))
 
 
-def _probed_cells(queries: np.ndarray, centroids: np.ndarray,
-                  nprobe: int) -> np.ndarray:
-    """The nprobe-nearest cells [Q, nprobe] of each query, recomputed on
-    host (Q x C, negligible next to the scan itself) so the jitted search
-    path stays untouched; feeds the probe's host-side stats."""
+def _probed_sizes(queries: np.ndarray, centroids: np.ndarray,
+                  cell_sizes: np.ndarray, nprobe: int) -> float:
+    """Mean members the probe scan evaluates per query — the IVF
+    ``distance_evals`` stat of the quantized IVF tiers, whose cells are
+    recomputed on the host (Q x C); the centroid scan is reported
+    separately by callers as ``centroid_evals``."""
     q = np.asarray(queries, np.float32)
     c = np.asarray(centroids, np.float32)
     d2 = (np.sum(q * q, 1)[:, None] - 2.0 * q @ c.T
           + np.sum(c * c, 1)[None, :])
     p = min(nprobe, c.shape[0])
-    return np.argpartition(d2, p - 1, axis=1)[:, :p]
-
-
-def _probed_sizes(queries: np.ndarray, centroids: np.ndarray,
-                  cell_sizes: np.ndarray, nprobe: int) -> float:
-    """Mean members the probe scan evaluates per query — the IVF
-    ``distance_evals`` stat; the centroid scan is reported separately by
-    callers as ``centroid_evals``."""
-    cells = _probed_cells(queries, centroids, nprobe)
+    cells = np.argpartition(d2, p - 1, axis=1)[:, :p]
     return float(cell_sizes[cells].sum(axis=1).mean())
+
+
+def flat_stage(operands: tuple, queries: jax.Array, *, k: int, n: int,
+               metric: str) -> tuple[jax.Array, jax.Array, None]:
+    """The single-device exact scan as a device part's function:
+    operands ``(db, alive)``."""
+    db, alive = operands
+    v, i = ds.search(queries, db, k, NULL_CTX, metric=metric, alive=alive,
+                     n=n)
+    return v, i, None
+
+
+def ivf_stage(operands: tuple, queries: jax.Array, *, k: int, k_req: int,
+              nprobe: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The IVF probe as a device part's function: operands ``(centroids,
+    lists, list_vecs, list_mask, extent, alive)``. ``alive`` folds into
+    the list mask (ids nulled too), so a tombstoned row can neither score
+    nor surface; the extent stays the build's prefix length, never counted
+    from the folded mask, since tombstones leave holes with live rows
+    behind them. Returns the top ``k`` padded to ``k_req`` (``-inf``/-1,
+    as FAISS) and the probed cells [Q, nprobe]."""
+    centroids, lists, list_vecs, mask, extent, alive = operands
+    if alive is not None:
+        mask = mask & alive[jnp.where(lists >= 0, lists, 0)]
+        lists = jnp.where(mask, lists, -1)
+    idx = ivf_lib.IVFIndex(centroids=centroids, lists=lists,
+                           list_vecs=list_vecs, list_mask=mask,
+                           extent=extent, spill=0)
+    v, i, cells = ivf_lib.probe(idx, queries, k, nprobe=nprobe)
+    v, i = _pad_result(v, i, k_req)
+    return v, i, cells
+
 
 
 # ---------------------------------------------------------------------------
@@ -410,6 +447,21 @@ class FlatIndex(VectorIndex):
             self._db = ds.place_rows(np.concatenate([self._rows(), nv]),
                                      self.ctx)
         self._n += int(nv.shape[0])
+
+    def device_search(self, k: int, alive: Optional[np.ndarray] = None,
+                      params: Optional[SearchParams] = None
+                      ) -> Optional[DevicePart]:
+        """The scan on one device; a mesh-sharded scan has none."""
+        del params
+        self._require_built()
+        if self.ctx.mesh is not None:
+            return None
+        al = None if alive is None else jnp.asarray(np.asarray(alive, bool))
+        return DevicePart(
+            "flat_scan", flat_stage, (self._db, al),
+            (("k", min(k, self.ntotal)), ("n", self._n),
+             ("metric", self.metric)),
+            lambda _: {"distance_evals": float(self.ntotal)})
 
     def search(self, queries: np.ndarray, k: int,
                alive: Optional[np.ndarray] = None,
@@ -559,17 +611,13 @@ class IVFFlatIndex(VectorIndex):
 
     @functools.cached_property
     def _probe(self):
-        """Jitted probe scan (static k/nprobe): one XLA call per search
-        instead of an eager op-by-op trace — the q=1 serving path is
-        dispatch-bound without this."""
-        def ivf_probe(q, centroids, lists, list_vecs, list_mask, extent, k,
-                      nprobe):
-            idx = ivf_lib.IVFIndex(centroids=centroids, lists=lists,
-                                   list_vecs=list_vecs, list_mask=list_mask,
-                                   extent=extent, spill=0)
-            return ivf_lib.search(idx, q, k, nprobe=nprobe)
+        """:func:`ivf_stage` as this index's own program (static knobs):
+        one XLA call per search instead of an eager op-by-op trace."""
+        def ivf_probe(operands, queries, k, k_req, nprobe):
+            return ivf_stage(operands, queries, k=k, k_req=k_req,
+                             nprobe=nprobe)
 
-        return jax.jit(ivf_probe, static_argnames=("k", "nprobe"))
+        return jax.jit(ivf_probe, static_argnames=("k", "k_req", "nprobe"))
 
     def set_params(self, params: SearchParams) -> None:
         """Adopt a tuned ``nprobe`` default. ``nprobe`` is fingerprint
@@ -577,51 +625,59 @@ class IVFFlatIndex(VectorIndex):
         if params.nprobe is not None:
             self.nprobe = params.nprobe
 
-    def search(self, queries: np.ndarray, k: int,
-               alive: Optional[np.ndarray] = None,
-               params: Optional[SearchParams] = None) -> SearchResult:
-        """Like FAISS, a query whose probed cells hold fewer than k members
-        pads the tail with index -1 / score -inf. ``alive`` folds into the
-        list mask (ids nulled too), so a tombstoned row can neither score
-        nor surface — the probe scan's own signature is unchanged.
+    def device_search(self, k: int, alive: Optional[np.ndarray] = None,
+                      params: Optional[SearchParams] = None) -> DevicePart:
+        """The probe (:func:`ivf_stage`) over this index's store. Like
+        FAISS, a query whose probed cells hold fewer than k members pads
+        the tail with index -1 / score -inf; ``alive`` folds into the list
+        mask inside the program.
 
         ``params.nprobe`` overrides ``self.nprobe`` for this call; it is
         ladder-snapped (``SearchParams`` guarantees it), so repeated
-        laddered calls reuse the same cached ``_probe`` jit entries —
-        zero recompiles once a rung is warm."""
+        laddered calls reuse the same compiled programs — zero recompiles
+        once a rung is warm."""
         self._require_built()
-        q = jnp.asarray(queries, jnp.float32)
-        nprobe = (self.nprobe if params is None or params.nprobe is None
-                  else params.nprobe)
-        nprobe = min(nprobe, int(self._ivf.centroids.shape[0]))
-        k_req = min(k, self.ntotal)
-        # the probe scan can surface at most nprobe * cell_cap rows
-        k_eff = min(k_req, nprobe * int(self._ivf.lists.shape[1]))
-        lists, mask = self._ivf.lists, self._ivf.list_mask
-        if alive is not None:
-            al = jnp.asarray(np.asarray(alive, bool))
-            mask = mask & al[jnp.where(lists >= 0, lists, 0)]
-            lists = jnp.where(mask, lists, -1)
+        with TraceAnnotation("ivf.probe"):
+            nprobe = (self.nprobe if params is None or params.nprobe is None
+                      else params.nprobe)
+            nprobe = min(nprobe, int(self._ivf.centroids.shape[0]))
+            k_req = min(k, self.ntotal)
+            # the probe scan can surface at most nprobe * cell_cap rows
+            k_eff = min(k_req, nprobe * int(self._ivf.lists.shape[1]))
+            iv = self._ivf
+            al = (None if alive is None
+                  else jnp.asarray(np.asarray(alive, bool)))
+            return DevicePart(
+                "ivf_probe", ivf_stage,
+                (iv.centroids, iv.lists, iv.list_vecs, iv.list_mask,
+                 iv.extent, al),
+                (("k", k_eff), ("k_req", k_req), ("nprobe", nprobe)),
+                self._probe_stats)
 
-        def run():
-            # the extent is the build's prefix length, never counted from
-            # ``mask``: tombstones leave holes with live rows behind them
-            v, i = self._probe(q, self._ivf.centroids, lists,
-                               self._ivf.list_vecs, mask, self._ivf.extent,
-                               k=k_eff, nprobe=nprobe)
-            return _pad_result(v, i, k_req)
-
+    def _probe_stats(self, cells: np.ndarray) -> dict[str, float]:
+        """Stats of the probed cells [Q, nprobe] the program returns."""
         with TraceAnnotation("ivf.count"):
-            cells = _probed_cells(queries, self._ivf.centroids, nprobe)
             sizes = self._cell_sizes[cells]
-            stats = {
+            return {
                 "distance_evals": float(sizes.sum(axis=1).mean()),
                 "centroid_evals": float(self._ivf.centroids.shape[0]),
                 "probe_bytes": float(ivf_lib.probe_bytes(self._ivf, sizes)
                                      .sum(axis=1).mean()),
             }
-        with TraceAnnotation("ivf.probe"):
-            return _timed(run, stats=stats)
+
+    def search(self, queries: np.ndarray, k: int,
+               alive: Optional[np.ndarray] = None,
+               params: Optional[SearchParams] = None) -> SearchResult:
+        """:meth:`device_search` run as its own program, ``ivf_probe``,
+        with one readback of the scores, ids and probed cells."""
+        part = self.device_search(k, alive, params)
+        q = jnp.asarray(queries, jnp.float32)
+        t0 = time.perf_counter()
+        v, i, cells = jax.device_get(
+            self._probe(part.operands, q, **dict(part.static)))
+        dt = time.perf_counter() - t0
+        return SearchResult(scores=v, indices=i, latency_s=dt,
+                            stats=part.stats(cells))
 
     def save(self, directory: str) -> None:
         self._require_built()
@@ -775,10 +831,13 @@ class TwoStageIndex(VectorIndex):
     def search(self, queries: np.ndarray, k: int,
                alive: Optional[np.ndarray] = None,
                params: Optional[SearchParams] = None) -> SearchResult:
+        """Where the reducer and the base both hand over device parts, the
+        whole search is one program (``twostage_search``): one upload of
+        the queries, one dispatch, one readback of the scores, ids and
+        stage 1's stats input. Else three calls: ``reducer.transform``,
+        ``base.search`` and the rerank, each with its readback."""
         self._require_built()
         t0 = time.perf_counter()
-        with TraceAnnotation("twostage.encode"):
-            zq = self.reducer.transform(np.asarray(queries, np.float32))
         k_eff = min(k, self.ntotal)
         # stage-1 candidate budget: an explicit (tuned / per-call) k1
         # beats the oversample formula; never below k_eff — the rerank
@@ -792,6 +851,56 @@ class TwoStageIndex(VectorIndex):
             k1 = min(k_eff * self.rerank_factor * over, self.ntotal)
         # tombstones are enforced in stage 1: a deleted row never appears
         # even as a pre-rerank candidate, so the rerank can't resurface it
+        parts = self._device_parts(k_eff, k1, alive, params)
+        if parts is None:
+            return self._search_in_calls(queries, k_eff, k1, alive, params,
+                                         t0)
+        encode, stage1, rerank = parts
+        with TraceAnnotation("twostage.dispatch"):
+            out = ts_lib.twostage_search(
+                jax.device_put(np.asarray(queries, np.float32)),
+                encode.operands, stage1.operands, rerank.operands,
+                encode=encode.spec, stage1=stage1.spec, rerank=rerank.spec)
+        with TraceAnnotation("twostage.fetch"):
+            scores, idx, aux = jax.device_get(out)
+            dt = time.perf_counter() - t0
+            stats = self._stats(stage1.stats(aux), k1)
+        return SearchResult(scores=scores, indices=idx, latency_s=dt,
+                            stats=stats)
+
+    def _device_parts(self, k_eff: int, k1: int,
+                      alive: Optional[np.ndarray],
+                      params: Optional[SearchParams]
+                      ) -> Optional[tuple[DevicePart, DevicePart, DevicePart]]:
+        """The encode, stage-1 and rerank parts of the one program, each
+        handed over under its stage's span; None where the reducer or the
+        base has none. A ``search`` set on the base instance (a wrapper of
+        the class's own) is what stage 1 is to be, and only the
+        three-call path calls it."""
+        encoder = getattr(self.reducer, "device_encoder", None)
+        if (encoder is None or "search" in vars(self.base)
+                or type(self.base).device_search is VectorIndex.device_search):
+            return None
+        with TraceAnnotation("twostage.encode"):
+            encode = encoder()
+        if encode is None:
+            return None
+        with TraceAnnotation("twostage.stage1"):
+            stage1 = self.base.device_search(k1, alive=alive, params=params)
+        if stage1 is None:
+            return None
+        with TraceAnnotation("twostage.rerank"):
+            rerank = DevicePart("rerank_candidates", ts_lib.rerank_stage,
+                                self._db_full,
+                                (("k", k_eff), ("metric", self.metric)))
+        return encode, stage1, rerank
+
+    def _search_in_calls(self, queries: np.ndarray, k_eff: int, k1: int,
+                         alive: Optional[np.ndarray],
+                         params: Optional[SearchParams],
+                         t0: float) -> SearchResult:
+        with TraceAnnotation("twostage.encode"):
+            zq = self.reducer.transform(np.asarray(queries, np.float32))
         with TraceAnnotation("twostage.stage1"):
             stage1 = self.base.search(zq, k1, alive=alive, params=params)
         with TraceAnnotation("twostage.rerank"):
@@ -801,15 +910,19 @@ class TwoStageIndex(VectorIndex):
             jax.block_until_ready((scores, idx))
             dt = time.perf_counter() - t0
             scores, idx = np.asarray(scores), np.asarray(idx)
-        # total work per query: stage-1 reduced-space evals + the k1
-        # full-space rerank distances
-        s1_evals = stage1.stats.get("distance_evals", 0.0)
-        stats = dict(stage1.stats)
+        return SearchResult(scores=scores, indices=idx, latency_s=dt,
+                            stats=self._stats(stage1.stats, k1))
+
+    @staticmethod
+    def _stats(stage1: dict[str, float], k1: int) -> dict[str, float]:
+        """Total work per query: stage-1 reduced-space evals + the k1
+        full-space rerank distances."""
+        s1_evals = stage1.get("distance_evals", 0.0)
+        stats = dict(stage1)
         stats.update({"distance_evals": s1_evals + float(k1),
                       "stage1_distance_evals": s1_evals,
                       "rerank_evals": float(k1)})
-        return SearchResult(scores=scores, indices=idx, latency_s=dt,
-                            stats=stats)
+        return stats
 
     def save(self, directory: str) -> None:
         self._require_built()

@@ -31,7 +31,12 @@ _ARRAYS = "arrays.npz"
 
 @runtime_checkable
 class Reducer(Protocol):
-    """Dimensionality reduction map R^n -> R^m."""
+    """Dimensionality reduction map R^n -> R^m.
+
+    A reducer whose fitted map runs on the device may also implement
+    ``device_encoder() -> DevicePart`` (``search.twostage``): ``transform``
+    as a pure device function of its fitted arrays, which lets
+    ``TwoStageIndex`` run a whole search as one program."""
 
     kind: str
     out_dim: int
@@ -272,6 +277,16 @@ class RAEReducer:
 
         return np.asarray(rae.rae_encode(self.params_,
                                          jnp.asarray(x, jnp.float32)))
+
+    def device_encoder(self):
+        """``transform`` as a device part: :func:`core.rae.encode` of the
+        fitted params, at ``EXACT`` as ``transform`` runs it."""
+        if self.params_ is None:
+            raise RuntimeError("rae: transform before fit")
+        from ..core import rae
+        from ..search.twostage import DevicePart
+
+        return DevicePart("rae_encode", rae.encode, self.params_)
 
     def fingerprint(self) -> str:
         """Content hash of the trained encoder (config + weights)."""

@@ -135,9 +135,17 @@ def probe_bytes(index: IVFIndex, sizes: np.ndarray) -> np.ndarray:
 def search(index: IVFIndex, queries: jax.Array, k: int, nprobe: int = 8
            ) -> tuple[jax.Array, jax.Array]:
     """Probe the nprobe nearest cells per query. Returns (scores [Q, k],
-    corpus row ids [Q, k]); scores = -squared-euclidean (higher = closer).
-    ``index.list_mask`` may have holes inside a cell's prefix (tombstones
-    folded in); ``index.extent`` is the prefix length the scan reads."""
+    corpus row ids [Q, k]); scores = -squared-euclidean (higher = closer)."""
+    v, ids, _ = probe(index, queries, k, nprobe)
+    return v, ids
+
+
+def probe(index: IVFIndex, queries: jax.Array, k: int, nprobe: int = 8
+          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`search`, also returning the probed cells [Q, nprobe] (what
+    the host's stats read). ``index.list_mask`` may have holes inside a
+    cell's prefix (tombstones folded in); ``index.extent`` is the prefix
+    length the scan reads."""
     q = jnp.asarray(queries, jnp.float32)
     cent = index.centroids
     d2c = (jnp.sum(q * q, 1)[:, None]
@@ -150,7 +158,8 @@ def search(index: IVFIndex, queries: jax.Array, k: int, nprobe: int = 8
     s = ivf_scan(q, cells, index.extent, index.list_vecs)[:, :, :cap]
     s = jnp.where(mask, s, -jnp.inf)
     v, flat = jax.lax.top_k(s.reshape(qn, p * cap), k)
-    return v, jnp.take_along_axis(ids.reshape(qn, p * cap), flat, axis=1)
+    return (v, jnp.take_along_axis(ids.reshape(qn, p * cap), flat, axis=1),
+            cells)
 
 
 def recall_vs_exact(index: IVFIndex, corpus: jax.Array, queries: jax.Array,

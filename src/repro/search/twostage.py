@@ -15,10 +15,16 @@ beam both pad short rows with id -1 — gathers the candidate vectors
 INSIDE the jit (XLA fuses the gather with the distance compute; the
 serving path pays one dispatch, not two), pins pad slots to -inf, and
 returns the exact top-k in the original space.
+
+:func:`twostage_search` runs a whole two-stage search as one device
+program: encode, stage 1 and rerank, each handed over as a
+:class:`DevicePart` by the reducer, the base index and the rerank.
 """
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from dataclasses import dataclass
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +64,60 @@ def rerank_candidates(queries: jax.Array, db_full: jax.Array,
     s = jnp.where(cand >= 0, s, -jnp.inf)
     v, sel = jax.lax.top_k(s, k)
     return v, jnp.take_along_axis(cand, sel, axis=1)
+
+
+def rerank_stage(db_full: jax.Array, queries: jax.Array, cand: jax.Array,
+                 *, k: int, metric: str) -> tuple[jax.Array, jax.Array]:
+    """:func:`rerank_candidates` in the form of a :class:`DevicePart`'s
+    function: its operand, the full-space corpus, first."""
+    return rerank_candidates(queries, db_full, cand, k, metric)
+
+
+@dataclass(frozen=True, eq=False)
+class DevicePart:
+    """One stage of a search as a pure device function and its operands,
+    for :func:`twostage_search` to run inside its one program.
+
+    ``fn(operands, *inputs, **dict(static))`` reads nothing but its
+    arguments. ``operands`` are the stage's arrays (a pytree): arguments
+    of the program, never closed over, so a corpus or a store is neither
+    baked into the program nor copied. ``static`` holds the stage's knobs
+    as hashable ``(name, value)`` pairs; the program compiles once per
+    ``spec``. ``name`` is the stage's ``jax.named_scope`` in the program
+    (the name of its own program elsewhere). A stage-1 part returns
+    ``(scores, ids, aux)``, and ``stats(aux)``, on the host, turns the
+    read-back ``aux`` into its ``SearchResult.stats``."""
+
+    name: str
+    fn: Callable
+    operands: Any
+    static: tuple = ()
+    stats: Callable[[Any], dict] = lambda aux: {}
+
+    @property
+    def spec(self) -> tuple:
+        """Everything of the part but its operands."""
+        return (self.name, self.fn, self.static)
+
+
+def _run_stage(spec: tuple, operands: Any, *inputs):
+    name, fn, static = spec
+    with jax.named_scope(name):
+        return fn(operands, *inputs, **dict(static))
+
+
+@functools.partial(jax.jit, static_argnames=("encode", "stage1", "rerank"))
+def twostage_search(queries: jax.Array, encode_ops: Any, stage1_ops: Any,
+                    rerank_ops: Any, *, encode: tuple, stage1: tuple,
+                    rerank: tuple) -> tuple[jax.Array, jax.Array, Any]:
+    """A two-stage search as one program: queries [Q, n] -> reduced
+    queries -> stage-1 candidates [Q, k1] -> full-space rerank. ``encode``,
+    ``stage1`` and ``rerank`` are the parts' specs, the ``*_ops`` their
+    operands. Returns (scores [Q, k], ids [Q, k], stage 1's aux)."""
+    z = _run_stage(encode, encode_ops, queries)
+    _, cand, aux = _run_stage(stage1, stage1_ops, z)
+    scores, ids = _run_stage(rerank, rerank_ops, queries, cand)
+    return scores, ids, aux
 
 
 def encode_corpus(rae_params, db: jax.Array, ctx: MeshCtx,

@@ -13,8 +13,14 @@ Where the time of a batch goes is traced, not counted: the engine and the
 index stack open ``jax.profiler.TraceAnnotation`` spans at each layer's
 boundary (``engine.batch`` with the batch's id, size and bucket,
 ``index.search``, ``engine.scatter``, ``twostage.encode`` / ``stage1`` /
-``rerank``, ``ivf.probe`` / ``count``, ``sharded.scan`` / ``merge``). With
-no profiler attached each is a check and a return; under
+``rerank``, ``twostage.dispatch`` / ``fetch``, ``ivf.probe`` / ``count``,
+``sharded.scan`` / ``merge``). Where a two-stage search is one program,
+``twostage.encode`` / ``stage1`` / ``rerank`` and ``ivf.probe`` each
+cover only that stage's handing over of its device part, ``dispatch``
+the upload and the enqueue, and ``fetch`` the wait, the readback and the
+stats (``ivf.count``); the device time of each stage is its
+``jax.named_scope`` inside the program ``twostage_search``. With no
+profiler attached each span is a check and a return; under
 ``jax.profiler.trace`` they land on the device trace's clock.
 """
 from __future__ import annotations

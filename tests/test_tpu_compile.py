@@ -20,6 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.api.index import ivf_stage
+from repro.core.rae import encode
 from repro.kernels.graph_beam.kernel import gather_layout, graph_beam_pallas
 from repro.kernels.graph_beam_q.kernel import graph_beam_q_pallas
 from repro.kernels.ivf_scan.kernel import ivf_scan_pallas, store_shape
@@ -27,6 +29,7 @@ from repro.kernels.l2_topk.kernel import l2_topk_pallas
 from repro.kernels.pq_adc.kernel import pq_adc_pallas
 from repro.kernels.topk_merge.kernel import topk_merge_pallas
 from repro.search.hnsw import _traverse_impl
+from repro.search.twostage import rerank_stage, twostage_search
 
 F32, I32 = jnp.float32, jnp.int32
 
@@ -100,6 +103,38 @@ def test_ivf_scan_compiles(one_chip, q_n, n_probe, n_cells, cap, d):
                      ((n_cells,), I32), (shape, F32)))).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < n_cells * cap * d
+
+
+@pytest.mark.parametrize("q_n,n,m,n_cells,cap,n_probe,k1,k", [
+    (16, 128, 64, 256, 9766, 16, 40, 10),        # RAE64,IVF256,Rerank4
+    (16, 768, 384, 1024, 2442, 64, 400, 100),    # RAE384,IVF1024,Rerank4
+])
+def test_twostage_search_compiles(one_chip, monkeypatch, q_n, n, m, n_cells,
+                                  cap, n_probe, k1, k):
+    """The one program of a two-stage search (encode, the IVF probe with
+    the ``ivf_scan`` kernel, the rerank) over 1M rows at the served
+    shapes: the store and the corpus are its arguments, read in place, so
+    its temporaries are a sliver of either (a copy would be its size)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, store = 1_000_000, store_shape(n_cells, cap, m)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = twostage_search.lower(
+        arg((q_n, n), F32), {"w_e": arg((n, m), F32)},
+        (arg((n_cells, m), F32), arg((n_cells, cap), I32), arg(store, F32),
+         arg((n_cells, cap), jnp.bool_), arg((n_cells,), I32), None),
+        arg((rows, n), F32),
+        encode=("rae_encode", encode, ()),
+        stage1=("ivf_probe", ivf_stage,
+                (("k", k1), ("k_req", k1), ("nprobe", n_probe))),
+        rerank=("rerank_candidates", rerank_stage,
+                (("k", k), ("metric", "euclidean")))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    store_bytes = store[0] * store[1] * store[2] * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < min(store_bytes, rows * n * 4) // 10, temp
 
 
 # graph hop shapes: Q 32 queries, N 20,000 nodes, d 64, W = ef = 64

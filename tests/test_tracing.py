@@ -19,6 +19,7 @@ import pytest
 
 from repro import api
 from repro.core import rae
+from repro.search import twostage
 from repro.serve import SearchEngine
 
 jax.config.update("jax_platform_name", "cpu")
@@ -26,7 +27,8 @@ jax.config.update("jax_platform_name", "cpu")
 N, DIM, K = 600, 16, 5
 TWO_STAGE_SPANS = {"engine.batch", "index.search", "engine.scatter",
                    "twostage.encode", "twostage.stage1", "twostage.rerank",
-                   "ivf.probe", "ivf.count"}
+                   "twostage.dispatch", "twostage.fetch", "ivf.probe",
+                   "ivf.count"}
 SHARDED_SPANS = {"engine.batch", "index.search", "engine.scatter",
                  "sharded.scan", "sharded.merge"}
 
@@ -143,21 +145,28 @@ def test_rae_encode_is_the_eager_encode_bitwise(two_stage, queries):
 
 def test_hot_path_programs_carry_stable_names(two_stage, corpus, queries):
     q = jnp.asarray(queries)
-    ivf = two_stage.base._ivf
     zq = jnp.asarray(two_stage.reducer.transform(queries))
     cand = jnp.zeros((len(queries), 20), jnp.int32)
     flat = api.FlatIndex().build(corpus)
+    probe = two_stage.base.device_search(20)
+    encode, stage1, rerank = two_stage._device_parts(K, 20, None, None)
     lowered = {
         "rae_encode": rae.rae_encode.lower(two_stage.reducer.params_, q),
-        "ivf_probe": two_stage.base._probe.lower(
-            zq, ivf.centroids, ivf.lists, ivf.list_vecs, ivf.list_mask,
-            ivf.extent, k=K, nprobe=8),
+        "ivf_probe": two_stage.base._probe.lower(probe.operands, zq,
+                                                 **dict(probe.static)),
         "rerank_candidates": two_stage._rerank.lower(
             q, two_stage._db_full, cand, k=K),
         "flat_scan": flat._scan.lower(q, flat._db, None, k=K, n=N),
+        "twostage_search": twostage.twostage_search.lower(
+            q, encode.operands, stage1.operands, rerank.operands,
+            encode=encode.spec, stage1=stage1.spec, rerank=rerank.spec),
     }
     for name, low in lowered.items():
         assert f"module @jit_{name} " in low.as_text(), name
+    # the one program keeps each stage findable by its scope
+    text = lowered["twostage_search"].as_text(debug_info=True)
+    for scope in ("rae_encode", "ivf_probe", "rerank_candidates"):
+        assert f"/{scope}/" in text, scope
 
 
 _SCOPES_SCRIPT = r"""
