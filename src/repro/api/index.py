@@ -350,10 +350,10 @@ def _probed_sizes(queries: np.ndarray, centroids: np.ndarray,
 def flat_stage(operands: tuple, queries: jax.Array, *, k: int, n: int,
                metric: str) -> tuple[jax.Array, jax.Array, None]:
     """The single-device exact scan as a device part's function:
-    operands ``(db, alive)``."""
-    db, alive = operands
+    operands ``(db, norms, alive)``."""
+    db, norms, alive = operands
     v, i = ds.search(queries, db, k, NULL_CTX, metric=metric, alive=alive,
-                     n=n)
+                     n=n, norms=norms)
     return v, i, None
 
 
@@ -390,12 +390,16 @@ class FlatIndex(VectorIndex):
     _fp_exempt = {
         "ctx": "mesh/sharding topology changes where the scan runs, not "
                "what it answers",
+        "_norms": "derived from _db, hashed through it",
     }
 
     def __init__(self, metric: str = "euclidean", ctx: MeshCtx = NULL_CTX):
         self.metric = metric
         self.ctx = ctx
         self._db: Optional[jax.Array] = None
+        # ||x||^2 of _db's rows, placed like them: the scan reads these
+        # instead of a second pass over the corpus on every batch
+        self._norms: Optional[jax.Array] = None
         self._n = 0  # real rows; a mesh-sharded _db carries pad rows after
 
     @property
@@ -425,14 +429,15 @@ class FlatIndex(VectorIndex):
     def build(self, corpus: np.ndarray) -> "FlatIndex":
         corpus = np.asarray(corpus, np.float32)
         self._db = ds.place_rows(corpus, self.ctx)
+        self._norms = ds.row_norms(self._db)
         self._n = int(corpus.shape[0])
         return self
 
     @functools.cached_property
     def _scan(self):
-        def flat_scan(q, db, alive, k, n):
+        def flat_scan(q, db, norms, alive, k, n):
             return ds.search(q, db, k, self.ctx, metric=self.metric,
-                             alive=alive, n=n)
+                             alive=alive, n=n, norms=norms)
 
         return jax.jit(flat_scan, static_argnames=("k", "n"))
 
@@ -446,6 +451,7 @@ class FlatIndex(VectorIndex):
         else:  # re-place: the sharded slabs are padded to equal size
             self._db = ds.place_rows(np.concatenate([self._rows(), nv]),
                                      self.ctx)
+        self._norms = ds.row_norms(self._db)
         self._n += int(nv.shape[0])
 
     def device_search(self, k: int, alive: Optional[np.ndarray] = None,
@@ -458,7 +464,7 @@ class FlatIndex(VectorIndex):
             return None
         al = None if alive is None else jnp.asarray(np.asarray(alive, bool))
         return DevicePart(
-            "flat_scan", flat_stage, (self._db, al),
+            "flat_scan", flat_stage, (self._db, self._norms, al),
             (("k", min(k, self.ntotal)), ("n", self._n),
              ("metric", self.metric)),
             lambda _: {"distance_evals": float(self.ntotal)})
@@ -470,7 +476,7 @@ class FlatIndex(VectorIndex):
         self._require_built()
         q = jnp.asarray(queries, jnp.float32)
         al = None if alive is None else jnp.asarray(np.asarray(alive, bool))
-        return _timed(lambda: self._scan(q, self._db, al,
+        return _timed(lambda: self._scan(q, self._db, self._norms, al,
                                          k=min(k, self.ntotal), n=self._n),
                       stats={"distance_evals": float(self.ntotal)})
 

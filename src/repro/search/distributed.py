@@ -1,10 +1,14 @@
 """Distributed exact vector search: sharded scan + global top-k merge.
 
-The corpus is row-sharded over every mesh axis ("db_rows"). Each shard runs
-the fused distance+top-k kernel (Pallas on TPU; jnp oracle elsewhere) over
-its slab; the global merge all-gathers only the per-shard (k values,
+The corpus is row-sharded over every mesh axis ("db_rows"). Each shard scores
+its slab with one XLA matmul at ``EXACT`` precision and takes its local
+``lax.top_k``; the global merge all-gathers only the per-shard (k values,
 k global indices) — k * n_shards scalars — and reduces them with the
-deterministic ``topk_merge`` kernel.
+deterministic ``topk_merge`` kernel. The euclidean score needs each row's
+``‖x‖²``: a caller that holds the corpus (``FlatIndex``) computes it once
+with :func:`row_norms` where the rows are placed and passes it in, sharded
+like the rows, so the scan reads its slab once per batch; without it the
+scan sums the slab's squares again on every call.
 
 Three invariants (regression-tested in tests/test_sharded.py) that the
 original version of this module violated:
@@ -111,9 +115,26 @@ def distributed_topk(scores: jax.Array, k: int, ctx: MeshCtx,
     return fn(scores)
 
 
+def _sq_norms(x: jax.Array) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    return jnp.sum(x32 * x32, -1)
+
+
+#: ``‖x‖²`` of every row of a placed corpus [N, d] -> [N] float32, the
+#: expression the euclidean scan computes when it is given none. Jitted, so
+#: the norms of a row-sharded corpus are summed on the devices and come out
+#: sharded like its rows.
+row_norms = jax.jit(_sq_norms)
+
+
 def sharded_scores(queries: jax.Array, db: jax.Array, metric: str,
-                   ctx: MeshCtx) -> jax.Array:
-    """[Q, N] similarity scores (higher = closer) with db row-sharded."""
+                   ctx: MeshCtx, norms: jax.Array | None = None
+                   ) -> jax.Array:
+    """[Q, N] similarity scores (higher = closer) with db row-sharded.
+
+    ``norms`` [N] are the rows' held ``row_norms(db)``, read by the
+    euclidean score in place of a pass over ``db``; ``None`` computes
+    them here. The cosine score normalises ``db`` on every call."""
     q32 = queries.astype(jnp.float32)
     db = ctx.constrain(db, "db_rows", None)
     d32 = db.astype(jnp.float32)
@@ -122,8 +143,8 @@ def sharded_scores(queries: jax.Array, db: jax.Array, metric: str,
         dn = d32 / jnp.maximum(jnp.linalg.norm(d32, -1, keepdims=True), 1e-12)
         s = jnp.matmul(qn, dn.T, precision=EXACT)
     elif metric == "euclidean":
-        q2 = jnp.sum(q32 * q32, -1)[:, None]
-        d2 = jnp.sum(d32 * d32, -1)[None, :]
+        q2 = _sq_norms(q32)[:, None]
+        d2 = (_sq_norms(d32) if norms is None else norms)[None, :]
         s = -(q2 - 2.0 * jnp.matmul(q32, d32.T, precision=EXACT)
               + d2)  # negative squared distance
     else:
@@ -154,7 +175,8 @@ def place_rows(x: np.ndarray, ctx: MeshCtx) -> jax.Array:
 
 def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
            metric: str = "euclidean", alive: jax.Array | None = None,
-           n: int | None = None) -> tuple[jax.Array, jax.Array]:
+           n: int | None = None, norms: jax.Array | None = None
+           ) -> tuple[jax.Array, jax.Array]:
     """Exact k-NN: returns (scores [Q, k], indices [Q, k]).
 
     ``alive`` (bool [N]) tombstones db rows: a dead row is pinned to
@@ -162,11 +184,13 @@ def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
     never surface — same contract as ``l2_topk``'s ``db_mask`` operand.
     ``alive=None`` leaves the static path bitwise untouched. ``n`` is the
     real row count of a ``db`` that :func:`place_rows` padded (default:
-    every row is real)."""
+    every row is real). ``norms`` (float32 [N]) are ``row_norms(db)``,
+    row-sharded like ``db``: the euclidean scan reads them instead of
+    summing every row's squares again (``None``: it does)."""
     n = db.shape[0] if n is None else n
     axes, n_shards = _shard_axes(ctx, "db_rows")
     if n_shards == 1:
-        s = sharded_scores(queries, db, metric, ctx)
+        s = sharded_scores(queries, db, metric, ctx, norms)
         if alive is None:
             return _padded_topk(s, k)
         s = jnp.where(alive[None, :], s, NEG_INF)
@@ -179,23 +203,28 @@ def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
     n_pad = n_loc * n_shards
     if n_pad > db.shape[0]:
         db = jnp.pad(db, ((0, n_pad - db.shape[0]), (0, 0)))
-    if alive is not None and n_pad > alive.shape[0]:
-        alive = jnp.pad(alive, (0, n_pad - alive.shape[0]))
+    rows = {}  # the optional per-row operands, row-sharded like db
+    for name, x in (("norms", norms), ("alive", alive)):
+        if x is not None:
+            rows[name] = (x if x.shape[0] == n_pad else
+                          jnp.pad(x, (0, n_pad - x.shape[0])))
     kl = min(k, n_loc)
     q_spec = ctx.pspec(queries.shape)          # queries replicated
     db_spec = ctx.pspec((n_pad, db.shape[1]), "db_rows", None)
+    row_spec = ctx.pspec((n_pad,), "db_rows")
     out_spec = ctx.pspec((queries.shape[0], k))
 
-    def f(q_l, db_l, *alive_l):
+    def f(q_l, db_l, rows_l):
         with jax.named_scope("shard_scan"):
-            s = sharded_scores(q_l, db_l, metric, MeshCtx(mesh=None))
+            s = sharded_scores(q_l, db_l, metric, MeshCtx(mesh=None),
+                               rows_l.get("norms"))
             shard = _linear_shard_index(mesh, axes)
             # pin pad rows BEFORE the local top-k: a padded (zero) row must
             # not displace a real candidate inside the shard
             grow = shard * n_loc + jnp.arange(s.shape[1], dtype=jnp.int32)
             keep = grow[None, :] < n
-            if alive_l:  # tombstones ride the same never-wins lane as pads
-                keep = keep & alive_l[0][None, :]
+            if "alive" in rows_l:  # tombstones: the never-wins lane of pads
+                keep = keep & rows_l["alive"][None, :]
             s = jnp.where(keep, s, NEG_INF)
             v, i = jax.lax.top_k(s, kl)             # [Q, kl] local
             gi = shard * n_loc + i
@@ -213,11 +242,8 @@ def search(queries: jax.Array, db: jax.Array, k: int, ctx: MeshCtx,
             gis = jax.lax.all_gather(gi, axes, axis=1, tiled=True)
             return topk_merge(vs, gis, k)
 
-    in_specs = (q_spec, db_spec)
-    args = (queries, db)
-    if alive is not None:
-        in_specs += (ctx.pspec((n_pad,), "db_rows"),)
-        args += (alive,)
-    fn = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(f, mesh=mesh,
+                       in_specs=(q_spec, db_spec,
+                                 {name: row_spec for name in rows}),
                        out_specs=(out_spec, out_spec), check_vma=False)
-    return fn(*args)
+    return fn(queries, db, rows)

@@ -12,6 +12,7 @@ rewrite of ``search/distributed.py`` fixed: dropped tail rows when
 gather-order-dependent tie resolution.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,57 @@ def test_sharded_bitwise_matches_flat(n, s):
                                   np.asarray(ref.indices))
     np.testing.assert_array_equal(np.asarray(got.scores),
                                   np.asarray(ref.scores))
+
+
+# ---------------------------------------------------------------------------
+# held row norms: the scan reads FlatIndex's ||x||^2 instead of summing them
+# ---------------------------------------------------------------------------
+def _slab_reduces(text: str, n: int, d: int) -> list[str]:
+    """Reductions in lowered StableHLO whose operand is an [n, d] slab."""
+    return re.findall(rf"stablehlo\.reduce\(.*: \(tensor<{n}x{d}xf32>",
+                      text)
+
+
+@pytest.mark.parametrize("n,k,tombstones", [
+    (101, 10, False), (101, 10, True), (7, 10, False), (7, 10, True)])
+def test_held_norms_scan_matches_recomputing_scan(n, k, tombstones):
+    """The scan over FlatIndex's held row norms answers bitwise as the scan
+    that sums them on every call (integer rows: every sum is exact), with
+    tombstones and with k > n."""
+    from repro.models.common import NULL_CTX
+    from repro.search import distributed as ds
+
+    corpus = _int_corpus(n, 16, seed=27)
+    q = jnp.asarray(_queries(5, 16, seed=28))
+    alive = (np.arange(n) % 3 != 1) if tombstones else None
+    al = None if alive is None else jnp.asarray(alive)
+    ix = FlatIndex().build(corpus)
+    np.testing.assert_array_equal(np.asarray(ix._norms),
+                                  np.sum(corpus * corpus, -1))
+    held = ds.search(q, ix._db, k, NULL_CTX, alive=al, norms=ix._norms)
+    fresh = ds.search(q, ix._db, k, NULL_CTX, alive=al)
+    for a, b in zip(held, fresh):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    got = ix.search(np.asarray(q), k, alive=alive)
+    np.testing.assert_array_equal(got.indices, np.asarray(fresh[1])[:, :n])
+    np.testing.assert_array_equal(got.scores, np.asarray(fresh[0])[:, :n])
+
+
+def test_flat_scan_makes_no_pass_over_the_corpus_for_norms():
+    """A one-device FlatIndex scan reduces no [n, d] operand: the row norms
+    come in as an operand, so a per-call norm pass over the corpus cannot
+    come back unseen. The recomputing scan shows the detector finds one."""
+    from repro.models.common import NULL_CTX
+    from repro.search import distributed as ds
+
+    n, d = 101, 16
+    ix = FlatIndex().build(_int_corpus(n, d, seed=29))
+    q = jnp.asarray(_queries(5, d, seed=30))
+    held = ix._scan.lower(q, ix._db, ix._norms, None, k=10, n=n).as_text()
+    assert _slab_reduces(held, n, d) == []
+    fresh = jax.jit(lambda q, db: ds.search(q, db, 10, NULL_CTX)).lower(
+        q, ix._db).as_text()
+    assert len(_slab_reduces(fresh, n, d)) == 1
 
 
 def test_sharded_invariant_across_shard_counts():
@@ -278,10 +330,12 @@ def test_mesh_sharded_index_matches_threads():
 _PLACE_ROWS_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import re
 import jax, numpy as np
 from repro.api import FlatIndex, index_factory
 from repro.launch.mesh import make_host_mesh
 from repro.models.common import MeshCtx
+from repro.search import distributed as ds
 
 rng = np.random.default_rng(26)
 corpus = rng.integers(-8, 8, (1001, 16)).astype(np.float32)  # 1001 % 4 != 0
@@ -297,13 +351,43 @@ ref = FlatIndex().build(corpus)
 got = ix.search(q, 10)
 np.testing.assert_array_equal(got.indices, ref.search(q, 10).indices)
 assert ix._shards[0].fingerprint() == ref.fingerprint()
+
+
+def slabs(a):
+    return sorted((s.device.id, s.index[0].start, s.data.shape[0])
+                  for s in a.addressable_shards)
+
+
+# the held row norms sit beside the rows: same devices, same slabs of 251
+flat = ix._shards[0]
+assert flat._norms.shape == (1004,) and slabs(flat._norms) == slabs(db)
+np.testing.assert_array_equal(np.asarray(flat._norms)[:1001],
+                              np.sum(corpus * corpus, -1))
+# the mesh scan over them answers bitwise as the recomputing one: with
+# tombstones, and with k = 300 > 251 rows per shard
+alive = jax.numpy.asarray(np.arange(1001) % 3 != 1)
+qj = jax.numpy.asarray(q)
+for k in (10, 300):
+    held = ds.search(qj, db, k, flat.ctx, alive=alive, n=1001,
+                     norms=flat._norms)
+    fresh = ds.search(qj, db, k, flat.ctx, alive=alive, n=1001)
+    for a, b in zip(held, fresh):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+# no shard sums its slab's squares on a batch
+text = flat._scan.lower(qj, db, flat._norms, None, k=10, n=1001).as_text()
+assert not re.findall(r"stablehlo\.reduce\(.*: \(tensor<251x16xf32>", text)
 # streaming insert re-places the grown corpus, ids continue after the tail
 extra = rng.integers(-8, 8, (7, 16)).astype(np.float32)
-ix._shards[0].add(extra)
+flat.add(extra)
 ref.add(extra)
-assert ix._shards[0].ntotal == 1008 == ref.ntotal
-np.testing.assert_array_equal(ix._shards[0].search(q, 10).indices,
+assert flat.ntotal == 1008 == ref.ntotal
+np.testing.assert_array_equal(flat.search(q, 10).indices,
                               ref.search(q, 10).indices)
+fresh_ix = FlatIndex(ctx=flat.ctx).build(np.concatenate([corpus, extra]))
+np.testing.assert_array_equal(np.asarray(flat._norms),
+                              np.asarray(fresh_ix._norms))
+assert slabs(flat._norms) == slabs(flat._db)
+assert flat.fingerprint() == ref.fingerprint()
 print("PLACE ROWS OK")
 """
 

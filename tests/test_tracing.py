@@ -156,7 +156,8 @@ def test_hot_path_programs_carry_stable_names(two_stage, corpus, queries):
                                                  **dict(probe.static)),
         "rerank_candidates": two_stage._rerank.lower(
             q, two_stage._db_full, cand, k=K),
-        "flat_scan": flat._scan.lower(q, flat._db, None, k=K, n=N),
+        "flat_scan": flat._scan.lower(q, flat._db, flat._norms, None,
+                                      k=K, n=N),
         "twostage_search": twostage.twostage_search.lower(
             q, encode.operands, stage1.operands, rerank.operands,
             encode=encode.spec, stage1=stage1.spec, rerank=rerank.spec),
@@ -183,8 +184,8 @@ corpus = rng.standard_normal((1001, 16)).astype(np.float32)
 q = rng.standard_normal((4, 16)).astype(np.float32)
 flat = index_factory("Shard4,Flat", ctx=MeshCtx(mesh=make_host_mesh())
                      ).build(corpus)._shards[0]
-text = flat._scan.lower(q, flat._db, None, k=10, n=1001).as_text(
-    debug_info=True)
+text = flat._scan.lower(q, flat._db, flat._norms, None, k=10,
+                        n=1001).as_text(debug_info=True)
 names = re.findall(r'loc\("([^"]+)"', text)
 for scope in ("shard_scan", "topk_merge"):
     ops = [n for n in names if n.startswith(scope + "/")]
